@@ -669,8 +669,8 @@ def bench_sharded_service():
     pipe-joined, which ``check.py --shards`` gates: their sums must equal
     the ``p1`` row's totals exactly — sharding moves work, never changes
     it).  ``mesh=1`` marks rows that ran on a real device mesh; ``mesh=0``
-    is the single-device vmap simulation (bit-identical, not parallel —
-    CI forces 8 host devices so the smoke row exercises the real path).
+    is the one-shard ``p1`` row, which needs none (CI forces 8 host devices
+    so the smoke row exercises the real path).
 
     One :class:`~repro.service.jobs.WaveTemplateCache` is shared across
     the whole ladder: the template is deliberately not keyed on P, so
@@ -891,10 +891,8 @@ def main(argv=None) -> None:
     ap.add_argument(
         "--shards", type=int, default=0, metavar="P",
         help="emit the sharded_service_*_p{1..P} scale-out ladder "
-        "(DESIGN.md §15); rows run on a real 'fleet' device mesh when "
-        "the host exposes >= P devices (CI forces 8 via "
-        "--xla_force_host_platform_device_count), else on the "
-        "bit-identical single-device vmap fallback (mesh=0 in derived)",
+        "(DESIGN.md §15) on a real 'fleet' device mesh; needs >= P "
+        "devices (CI forces 8 via --xla_force_host_platform_device_count)",
     )
     ap.add_argument(
         "--json", default=None, metavar="PATH",
@@ -913,6 +911,9 @@ def main(argv=None) -> None:
         "as JSONL here (plus Prometheus text exposition at PATH.prom)",
     )
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     DISPATCH = args.dispatch
     CHUNK = args.chunk
     SMOKE = args.smoke

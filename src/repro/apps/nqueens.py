@@ -11,7 +11,9 @@ import jax.numpy as jnp
 from ..core.program import HeapVar, InitialTask, Program, TaskType
 from .registry import AppCase, register_case
 
-SOLUTIONS = {4: 2, 5: 10, 6: 4, 7: 40, 8: 92, 9: 352, 10: 724}
+SOLUTIONS = {
+    4: 2, 5: 10, 6: 4, 7: 40, 8: 92, 9: 352, 10: 724, 11: 2680, 12: 14200,
+}
 
 
 def make_program(n: int) -> Program:

@@ -28,8 +28,8 @@ into online decisions:
   workload mix (structural-type diversity vs per-shard imbalance),
   closing the ROADMAP note that placement policy was still static.
 
-The :class:`CostModel` defaults to the roofline constants in
-``benchmarks/roofline.py`` (V_inf critical-path prices); a one-shot
+The :class:`CostModel` defaults to fixed V_inf critical-path prices
+(``_DISPATCH_LATENCY_S``/``_TRANSFER_LATENCY_S``); a one-shot
 :meth:`CostModel.calibrated` micro-probe measures this host's actual
 dispatch round-trip and per-lane slope instead, cached process-wide (and
 optionally on disk) so steady state never pays probing.
@@ -44,16 +44,10 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 
 from ..obs.metrics import RollingWindow
 
-# Roofline constants are the calibration fallback: pulled from
-# benchmarks/roofline.py when importable (the benchmarks/ directory is not
-# a package on sys.path in library use), else the same literals.
+# V_inf launch/readback prices used until CostModel.calibrated measures
+# this host (the same literals benchmarks/roofline.py prints with).
 _DISPATCH_LATENCY_S = 40e-6
 _TRANSFER_LATENCY_S = 15e-6
-try:  # pragma: no cover - import path depends on caller's sys.path
-    from benchmarks.roofline import DISPATCH_LATENCY_S as _DISPATCH_LATENCY_S
-    from benchmarks.roofline import TRANSFER_LATENCY_S as _TRANSFER_LATENCY_S
-except Exception:
-    pass
 
 AUTO_MODES = ("masked", "compacted", "gather")
 RESIDENT_AUTO_MODES = ("masked", "gather")

@@ -429,6 +429,10 @@ class EpochLoop:
         # traced body, same bits, one fused kernel per chunk (DESIGN.md §12)
         self.megakernel = bool(megakernel)
         self.megakernel_impl = megakernel_impl
+        if self.megakernel:
+            from ..kernels import epoch_megakernel as mk
+
+            mk.resolve_impl(megakernel_impl)  # refuse on TPU up front
         # trace-counter hook: every traced builder body bumps this at trace
         # time (tracing executes the Python body; cached executions do not),
         # so "two identical consecutive waves retraced nothing" is a
@@ -1150,22 +1154,28 @@ class EpochLoop:
         ``shard_map``: each device owns one shard's block and drives its
         own resident ``while_loop`` — shards advance *independently* to
         their bounds inside the one launch, no cross-shard lockstep.
-        Without a mesh the fleet falls back to ``vmap`` over the shard
-        axis (single-device simulation): jax batches the while_loop as
-        "while any shard's cond holds" with finished shards' carries
-        frozen by ``select`` — bit-identical per shard, just not
-        device-parallel.
+        ``mesh=None`` is the single-device simulation, asked for by
+        name: ``vmap`` over the shard axis, which jax batches as "while
+        any shard's cond holds" with finished shards' carries frozen by
+        ``select`` — bit-identical per shard, just not device-parallel.
+        Under ``vmap`` every ``lax.switch`` of the span ladder becomes a
+        select over all its rungs, so one shard runs without it.
 
-        ``megakernel=True`` composes on the mesh path (each device runs
-        its chunk through the persistent Pallas kernel); the vmap
-        fallback drives the kernel's ``lax.while_loop`` oracle instead —
-        the two are bit-identical by construction (DESIGN.md §12), so the
-        fallback changes nothing observable.
+        ``megakernel=True`` needs the mesh path (each device runs its
+        chunk through the persistent Pallas kernel); with ``mesh=None``
+        it raises rather than quietly running the ``while_loop`` oracle.
 
         Compiled once per (shards, regions, capacity, depth, driver) and
         cached next to the solo chunk templates; ``limits`` stays dynamic
         so K adaptation and per-shard staggering never retrace.
         """
+        if self.megakernel and mesh is None:
+            raise ValueError(
+                "megakernel=True runs each shard's chunk as a Pallas "
+                "kernel on its own device; the mesh=None vmap simulation "
+                "has no such device.  Use a real 'fleet' mesh or "
+                "megakernel=False."
+            )
         capacity = int(carry.state.task.shape[-1])
         depth = int(carry.jstack.shape[-1])
         key = ("fleet", n_shards, n_regions, capacity, depth,
@@ -1176,8 +1186,7 @@ class EpochLoop:
             def cond(cc: ResidentCarry, lim):
                 return (cc.sp > 0).any() & (cc.n_epochs < lim)
 
-            use_megakernel = self.megakernel and mesh is not None
-            if use_megakernel:
+            if self.megakernel:
                 from ..kernels import epoch_megakernel as mk
 
                 impl = self.megakernel_impl
@@ -1192,25 +1201,31 @@ class EpochLoop:
                         lambda cc: cond(cc, lim), body, c
                     )
 
-            if mesh is None:
+            def shard_fn(c, lim):
+                # one shard's block with the fleet axis still present
+                # (size 1): squeeze, run the solo chunk, re-expand
+                c1 = jax.tree.map(lambda x: x[0], c)
+                out = one_shard(c1, lim[0])
+                return jax.tree.map(lambda x: x[None], out)
+
+            if mesh is None and n_shards == 1:
+                # vmap would batch the span ladder's lax.switch into a
+                # select over every rung, so each epoch would step every
+                # launch width, the full-TV one included
+                loop = jax.jit(shard_fn)
+            elif mesh is None:
                 loop = jax.jit(jax.vmap(one_shard))
             else:
-                from ..launch.mesh import fleet_shard_map
-
                 spec = jax.tree.map(lambda _: _FLEET_SPEC, carry)
 
-                def shard_fn(c, lim):
-                    # shard_map hands each device its block with the
-                    # fleet axis still present (size 1): squeeze, run the
-                    # solo chunk, re-expand
-                    c1 = jax.tree.map(lambda x: x[0], c)
-                    out = one_shard(c1, lim[0])
-                    return jax.tree.map(lambda x: x[None], out)
-
-                loop = jax.jit(fleet_shard_map(
-                    shard_fn, mesh,
+                # the per-shard chunk bodies are closed computations with
+                # no cross-shard collectives, so there is no varying-axes
+                # bookkeeping to check (and the Pallas fork kernels inside
+                # declare none)
+                loop = jax.jit(jax.shard_map(
+                    shard_fn, mesh=mesh,
                     in_specs=(spec, _FLEET_SPEC),
-                    out_specs=spec,
+                    out_specs=spec, check_vma=False,
                 ))
             self._resident_cache[key] = loop
         return self._resident_cache[key](

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import ops as kops
 from .primitives import EpochCtx, MapCtx
 from .program import Program
 
@@ -444,8 +445,11 @@ def commit_epoch(
 ) -> Tuple[TVMState, Dict[str, jnp.ndarray], EpochSummary, List[MapLaunch]]:
     """Phase 3: prefix-sum fork allocation + TMS (epoch-number) update.
 
-    ``fork_offsets_fn(counts) -> (excl_offsets, total)`` lets the engine swap
-    the jnp cumsum for the ``fork_compact.fork_scan`` Pallas kernel.
+    Fork allocation defaults to ``kernels.ops.fork_offsets`` (the
+    ``fork_compact.fork_scan`` Pallas kernel on TPU, its jnp reference
+    elsewhere): XLA's own cumsum lowers on TPU to a reduce_window that takes
+    tens of seconds to compile at 2^20 lanes, once per launch width.
+    ``fork_offsets_fn(counts) -> (excl_offsets, total)`` overrides it.
 
     With ``arena`` (the service's multi-tenant mode) the single global
     ``nextFreeCore`` becomes one cursor per job region: every lane is tagged
@@ -457,9 +461,8 @@ def commit_epoch(
     lane's own job epoch number), and the summary is a
     :class:`MuxEpochSummary` carrying the per-job readback scalars.
     ``seg_offsets_fn(counts, seg, n_segs) -> (excl_offsets, seg_totals)`` is
-    the arena counterpart of ``fork_offsets_fn``: it defaults to the jnp
-    reference and can be swapped for the ``fork_compact.segmented_fork_scan``
-    Pallas kernel (``kernels.ops.segmented_fork_offsets``).  This whole
+    the arena counterpart of ``fork_offsets_fn``: it overrides
+    ``kernels.ops.segmented_fork_offsets``.  This whole
     function is ``lax.while_loop``-traceable in both modes — the resident
     drivers carry the arena (cursors included) through the loop.
     """
@@ -477,11 +480,9 @@ def commit_epoch(
 
     lane_cap = None  # per-lane scatter bound (arena mode only)
     if arena is None:
-        if fork_offsets_fn is None:
-            lane_excl = _exclusive_cumsum(lane_count)
-            total_forks = lane_count.sum().astype(jnp.int32)
-        else:
-            lane_excl, total_forks = fork_offsets_fn(lane_count)
+        lane_excl, total_forks = (fork_offsets_fn or kops.fork_offsets)(
+            lane_count
+        )
         lane_base = state.next_free + lane_excl
         overflow = (state.next_free + total_forks) > C
     else:
@@ -489,14 +490,9 @@ def commit_epoch(
         jl = jnp.clip(arena.slot_job[cidx], 0, J - 1)  # region per lane
         # segmented exclusive scan: each lane's offset among *its own job's*
         # forks — identical to the solo cumsum restricted to that region
-        if seg_offsets_fn is None:
-            from ..kernels import ref as _kref
-
-            lane_excl, job_forks = _kref.segmented_fork_scan_ref(
-                lane_count, jl, J
-            )
-        else:
-            lane_excl, job_forks = seg_offsets_fn(lane_count, jl, J)
+        lane_excl, job_forks = (
+            seg_offsets_fn or kops.segmented_fork_offsets
+        )(lane_count, jl, J)
         job_forks = job_forks.astype(jnp.int32)
         lane_base = arena.next[jl] + lane_excl
         lane_cap = arena.end[jl]

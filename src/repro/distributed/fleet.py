@@ -9,9 +9,10 @@ leading "fleet" axis and advanced together:
 * **One fused launch per collective chunk.**
   :meth:`~repro.core.engine.EpochLoop.run_chunk_fleet` runs every shard's
   resident chunk inside one compiled program — ``shard_map`` over the 1-D
-  ``"fleet"`` mesh (:func:`repro.launch.mesh.make_fleet_mesh`) when
-  enough devices are attached, a bit-identical ``vmap`` simulation
-  otherwise — with each shard bounded by its *own* dynamic epoch limit.
+  ``"fleet"`` mesh (:func:`repro.launch.mesh.make_fleet_mesh`), or a
+  bit-identical single-device ``vmap`` simulation when the caller passes
+  ``mesh=None`` — with each shard bounded by its *own* dynamic epoch
+  limit.
 
 * **One readback per collective chunk.**  The per-shard
   :class:`~repro.core.engine.ChunkSummary` scalars come back stacked in a
@@ -45,8 +46,9 @@ from typing import Any, Callable, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
-from ..core.engine import resolve_resident_dispatch
+from ..core.engine import _FLEET_SPEC, resolve_resident_dispatch
 from ..core.scheduler import RunStats
 from ..obs.trace import NULL_TRACER
 from ..service.jobs import (
@@ -110,12 +112,13 @@ class ShardedFleet:
     path.  Drive with :meth:`step` / :meth:`run`; completions stream per
     boundary exactly like a single ``DeviceMultiplexer`` wave.
 
-    ``mesh="auto"`` takes a real ``"fleet"`` device mesh when the host
-    has >= P devices (each shard's resident loop runs on its own device)
-    and falls back to the single-device ``vmap`` simulation otherwise —
-    same bits either way.  ``rebalance=False`` pins every job to its
-    placed shard (sticky affinity); the default migrates queued jobs off
-    hot shards at boundaries and counts each move in ``migrations``.
+    ``mesh="auto"`` builds a real ``"fleet"`` device mesh (each shard's
+    resident loop runs on its own device) and raises when fewer than P
+    devices are attached; ``mesh=None`` asks for the single-device
+    ``vmap`` simulation by name — same bits either way.
+    ``rebalance=False`` pins every job to its placed shard (sticky
+    affinity); the default migrates queued jobs off hot shards at
+    boundaries and counts each move in ``migrations``.
     """
 
     def __init__(
@@ -405,7 +408,16 @@ class ShardedFleet:
             ]
         else:
             return self._fcarry
-        self._fcarry = jax.tree.map(lambda *xs: jnp.stack(xs), *parts)
+        if self.mesh is None:
+            self._fcarry = jax.tree.map(lambda *xs: jnp.stack(xs), *parts)
+        else:
+            # from the host straight onto the mesh, one block per device:
+            # a restack never stages the whole fleet on the default device
+            parts = jax.device_get(parts)
+            self._fcarry = jax.device_put(
+                jax.tree.map(lambda *xs: np.stack(xs), *parts),
+                NamedSharding(self.mesh, _FLEET_SPEC),
+            )
         self._host = None
         for p, sh in enumerate(self._shards):
             if self._fresh[p]:

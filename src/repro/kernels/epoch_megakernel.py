@@ -21,14 +21,21 @@ megakernel and the while_loop baseline are bit-identical by construction —
 ``kernels/ref.py::epoch_chunk_ref`` is that baseline, packaged as this
 kernel's oracle.
 
-Backend dispatch follows ``ops.py``: "pallas" on TPU, the jnp oracle on
-CPU, "interpret" to execute the kernel body through the Pallas interpreter
-(the CI parity path on this CPU container).  Grid strategy: one program
-instance owning the full TV — the epoch body is already lane-vectorized
-(VPU-shaped masked/gather steps), and lanes interact every epoch through
-the fork prefix sum and the stack push, so a lane-partitioned grid would
-need cross-program reductions per epoch; see DESIGN.md §12 for the
-single-block rationale and the TPU scaling notes.
+Backend dispatch: the jnp oracle on CPU, "interpret" to execute the kernel
+body through the Pallas interpreter (the CPU parity path of the tests).  On
+TPU the kernel is refused (:func:`resolve_impl`): Mosaic cannot lower the
+real resident body.  Compiled for a v5e, its zero-width arena planes fail
+first ("vector types must have positive constant sizes"), and a carry
+without them fails in the gather lowering rule, which takes only
+same-shape ``take_along_axis`` gathers, not the body's lane-pack and heap
+gathers.  ``tests/test_tpu_compile.py`` keeps that compile as a test, so
+a Mosaic that learns to lower it shows up there.
+
+Grid strategy: one program instance owning the full TV — the epoch body
+is already lane-vectorized (VPU-shaped masked/gather steps), and lanes
+interact every epoch through the fork prefix sum and the stack push, so a
+lane-partitioned grid would need cross-program reductions per epoch; see
+DESIGN.md §12 for the single-block rationale and the TPU scaling notes.
 """
 from __future__ import annotations
 
@@ -39,12 +46,33 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _default_impl() -> str:
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+IMPLS = ("auto", "pallas", "interpret", "ref")
 
 
-def _resolve(impl: str) -> str:
-    return _default_impl() if impl in ("auto", None) else impl
+def resolve_impl(impl: str) -> str:
+    """The concrete implementation for ``impl`` on this backend.
+
+    "auto" is the ``lax.while_loop`` oracle ("ref") off TPU.  On TPU,
+    "auto" and "pallas" raise: the resident epoch body does not lower
+    through Mosaic (see the module docstring), and running the oracle
+    instead would pass a ``while_loop`` off as the megakernel.  Callers
+    that want the resident loop on TPU use ``megakernel=False``.
+    """
+    if impl not in IMPLS:
+        raise ValueError(
+            f"epoch_chunk impl must be one of {IMPLS}, got {impl!r}"
+        )
+    if jax.default_backend() == "tpu" and impl in ("auto", "pallas"):
+        raise NotImplementedError(
+            "the epoch megakernel does not lower on TPU: Mosaic refuses "
+            "the resident epoch body's gathers (lane pack, heap reads) and "
+            "its zero-width arena planes.  Run the resident loop with "
+            "megakernel=False (the lax.while_loop chunk), or "
+            "megakernel_impl='interpret' off TPU."
+        )
+    if impl == "auto":
+        return "ref"
+    return impl
 
 
 def epoch_chunk(
@@ -59,21 +87,16 @@ def epoch_chunk(
     ``carry`` is any pytree (the drivers pass a
     :class:`~repro.core.engine.ResidentCarry`); ``limit`` is the dynamic
     chunk bound (i32 scalar).  Returns the carry after the chunk, with the
-    same pytree structure.  ``impl``: "pallas" (native TPU), "interpret"
+    same pytree structure.  ``impl``: "pallas" (native), "interpret"
     (Pallas interpreter), "ref" (the ``lax.while_loop`` oracle), "auto"
-    (platform default).
+    (platform default; see :func:`resolve_impl`).
     """
-    impl = _resolve(impl)
+    impl = resolve_impl(impl)
     limit = jnp.asarray(limit, jnp.int32)
     if impl == "ref":
         from . import ref
 
         return ref.epoch_chunk_ref(cond_fn, body_fn, carry, limit)
-    if impl not in ("pallas", "interpret"):
-        raise ValueError(
-            f"epoch_chunk impl must be 'pallas', 'interpret', 'ref' or "
-            f"'auto', got {impl!r}"
-        )
     return _epoch_chunk_pallas(
         cond_fn, body_fn, carry, limit, interpret=(impl == "interpret")
     )

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input shape) on
 the production meshes and record memory / cost / collective statistics.
 
@@ -17,6 +14,7 @@ single cells).  benchmarks/roofline.py consumes them.
 import argparse
 import functools
 import json
+import os
 import pathlib
 import re
 import time
@@ -430,6 +428,9 @@ def main() -> None:
         help="config overrides, e.g. --set remat=dots --set param_dtype=bf16",
     )
     args = ap.parse_args()
+    # 512 host devices stand in for the production meshes; set before the
+    # first jax call initializes a backend, never at import
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     pods = {"0": [False], "1": [True], "both": [False, True]}[args.multi_pod]
     archs = (

@@ -29,42 +29,24 @@ def make_fleet_mesh(shards: int):
     lives on device ``p`` and runs its own resident chunk loop under
     ``shard_map``.
 
-    Returns ``None`` when fewer than ``shards`` devices are attached —
-    the fleet then falls back to its single-device ``vmap`` simulation
-    (bit-identical, not device-parallel), so P > device_count is a
-    degraded mode, never an error.  CI forces 8 host devices
-    (``--xla_force_host_platform_device_count=8``) to exercise the real
-    path on CPU.
+    One shard needs no mesh (``None``: its one block stays on the default
+    device).  More shards than attached devices is an error: the
+    single-device ``vmap`` simulation is asked for by name, with
+    ``mesh=None``, never substituted for a missing device.
     """
     if shards < 1:
         raise ValueError(f"a fleet needs >= 1 shard, got {shards}")
-    if shards == 1 or len(jax.devices()) < shards:
+    if shards == 1:
         return None
-    try:
-        return jax.make_mesh(
-            (shards,), ("fleet",),
-            axis_types=(jax.sharding.AxisType.Auto,),
+    have = len(jax.devices())
+    if have < shards:
+        raise ValueError(
+            f"a {shards}-shard fleet needs {shards} devices, but "
+            f"{have} {jax.default_backend()} device(s) are attached; pass "
+            "mesh=None to run the single-device vmap simulation"
         )
-    except (AttributeError, TypeError):
-        # older jax: no AxisType / no axis_types kwarg
-        return jax.make_mesh((shards,), ("fleet",))
-
-
-def fleet_shard_map(fn, mesh, *, in_specs, out_specs):
-    """``shard_map`` across jax versions (``jax.shard_map`` when present,
-    the experimental module otherwise).  The fleet's per-shard chunk
-    bodies are closed computations — no cross-shard collectives — so
-    replication checking is irrelevant and disabled where the API
-    requires an explicit opt-out."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+    return jax.make_mesh(
+        (shards,), ("fleet",), axis_types=(jax.sharding.AxisType.Auto,)
     )
 
 

@@ -67,11 +67,13 @@ def init_model(cfg: ModelConfig, key: jax.Array):
     return pb.values, pb.axes
 
 
-def _layer_windows(cfg: ModelConfig) -> jnp.ndarray:
-    """Per-layer attention window (0 = full/global)."""
+def _layer_windows(cfg: ModelConfig) -> Optional[jnp.ndarray]:
+    """Per-layer attention window (0 = full/global), or None when every
+    layer is full: the layer scan then keeps the window a static 0, which
+    the Pallas attention kernels need (they specialize on it)."""
     L = cfg.n_layers
     if cfg.sliding_window <= 0:
-        return jnp.zeros((L,), jnp.int32)
+        return None
     win = jnp.full((L,), cfg.sliding_window, jnp.int32)
     if cfg.global_layer_every > 0:
         is_global = (jnp.arange(L) % cfg.global_layer_every) == 0
@@ -92,7 +94,7 @@ def _decoder_layer(
     cfg: ModelConfig,
     p: Dict[str, Any],      # per-layer slice
     x: jnp.ndarray,         # (B, S, d)
-    window: jnp.ndarray,    # scalar i32, 0 = full
+    window,                 # static 0 or scalar i32; 0 = full
     enc_out: Optional[jnp.ndarray],
     collect_kv: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, Dict[str, jnp.ndarray]]:
@@ -184,6 +186,7 @@ def forward(
 
     def body(h, xs):
         pl, win = xs
+        win = 0 if win is None else win
         h, aux, kv = _decoder_layer(cfg, pl, h, win, enc_out, collect_kv)
         return h, (aux, kv)
 
@@ -373,6 +376,7 @@ def decode_step(
 
     def body(h, xs):
         pl, win, kv = xs
+        win = 0 if win is None else win
         aux_out = {}
         mix = jnp.zeros_like(h)
         hn = apply_norm(pl, "norm1", cfg, h)

@@ -170,13 +170,10 @@ class SpanTracer(NullTracer):
     def annotation(self, name: str):
         """``jax.profiler.TraceAnnotation`` wrapping a device launch, so an
         XLA profile collected alongside shows the same phase names as the
-        runtime timeline.  Falls back to a no-op where unavailable."""
-        try:
-            import jax.profiler
+        runtime timeline."""
+        import jax.profiler
 
-            return jax.profiler.TraceAnnotation(name)
-        except Exception:  # pragma: no cover - profiler always present
-            return contextlib.nullcontext()
+        return jax.profiler.TraceAnnotation(name)
 
     # ----------------------------------------------------------- queries
     def events_named(self, name: str, cat: Optional[str] = None

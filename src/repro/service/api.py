@@ -152,6 +152,9 @@ class JobService:
     (``round_robin`` / ``least_loaded`` / ``sticky``) assigns queued jobs
     to shards; ``rebalance`` migrates jobs off hot shards at chunk
     boundaries.  Per-job results stay bit-identical to solo at every P.
+    ``mesh`` is the fleet's device mesh: ``"auto"`` builds one over P
+    attached devices (and raises with fewer), ``None`` runs the P shards
+    as the single-device ``vmap`` simulation.
 
     ``calibrate`` (default on) seeds ``dispatch="auto"``'s controller
     with a :meth:`~repro.control.controller.CostModel.calibrated` micro
@@ -179,6 +182,7 @@ class JobService:
         metrics=None,
         tracer=None,
         shards: int = 1,
+        mesh: Any = "auto",
         placement: str = "round_robin",
         rebalance: bool = True,
         calibrate: bool = True,
@@ -203,6 +207,10 @@ class JobService:
                     f"placement must be one of {PLACEMENTS + ('auto',)}, "
                     f"got {placement!r}"
                 )
+            if mesh == "auto":
+                from ..launch.mesh import make_fleet_mesh
+
+                mesh = make_fleet_mesh(shards)  # refuses P > devices
         elif shards != 1:
             raise ValueError(
                 "shards requires engine='sharded' (host/device waves run "
@@ -245,6 +253,7 @@ class JobService:
             )
         self.engine = engine
         self.shards = int(shards)
+        self.mesh = mesh
         self.placement = placement
         self.rebalance = bool(rebalance)
         self.stack_depth = stack_depth
@@ -701,6 +710,7 @@ class JobService:
                         controller=self.controller,
                         chunk_controller=self.chunk_controller,
                         queue_probe=self._queue_probe,
+                        mesh=self.mesh,
                     )
                     tpl_built = self._mux.template
                     # the whole queue streams into the fleet's placement

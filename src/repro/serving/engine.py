@@ -89,6 +89,9 @@ class EpochServer:
             lambda p, t, c: decode_step(p, cfg, t, c)
         )
         self._prefill_cache: Dict[int, object] = {}
+        # (request ids, last-token logits) of the latest admission's
+        # prefill, left on the device, for callers that check the first step
+        self.last_prefill = None
 
     # ----------------------------------------------------------- frontend
     def submit(self, req: Request) -> int:
@@ -138,6 +141,7 @@ class EpochServer:
             self.params, jnp.asarray(toks),
             jnp.asarray(np.asarray(plens, np.int32) - 1), ef,
         )
+        self.last_prefill = ([r.rid for r in reqs], logits)
         next_tok = np.asarray(jnp.argmax(logits, -1))
 
         # scatter the prefilled caches into the allocated slots (coalesced

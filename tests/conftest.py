@@ -1,50 +1,53 @@
 """Tier-1 test-suite bootstrap.
 
-Two environment guards so `PYTHONPATH=src python -m pytest -x -q` collects
-and runs everywhere (dev laptops, CI, hermetic containers):
+``multidevice_skip`` gates the distributed tests, which need >= 4 devices.
+On CPU they force host devices in a subprocess
+(``XLA_FLAGS=--xla_force_host_platform_device_count``, the SNIPPETS.md
+idiom); on an accelerator with fewer devices they skip rather than fail.
 
-1. **hypothesis fallback** — the property tests import ``hypothesis`` (a
-   dev dependency, see ``requirements-dev.txt``).  Where it cannot be
-   installed, a minimal deterministic stub (``tests/_hypothesis_stub.py``)
-   is injected into ``sys.modules`` so the modules still collect and the
-   property tests run as seeded-random smoke tests.
-
-2. **multi-device gating** — the distributed tests need >= 4 devices
-   (they subprocess with ``XLA_FLAGS=--xla_force_host_platform_device_count``,
-   the SNIPPETS.md idiom) plus a jax new enough for
-   ``jax.sharding.AxisType``.  ``multidevice_skip`` centralizes the check;
-   the affected modules apply it as a ``skipif`` marker instead of failing.
+``solo_run`` is the solo reference run many fleet tests compare against,
+computed once per test process; ``assert_matches_solo`` compares a job's
+result with it.
 """
 from __future__ import annotations
 
-import importlib.util
-import pathlib
-import sys
-
-_HERE = pathlib.Path(__file__).resolve().parent
-
-# ---------------------------------------------------------------- guard 1
-if importlib.util.find_spec("hypothesis") is None:
-    sys.path.insert(0, str(_HERE))
-    import _hypothesis_stub as _stub
-
-    sys.modules["hypothesis"] = _stub  # type: ignore[assignment]
-    sys.modules["hypothesis.strategies"] = _stub.strategies
+import functools
 
 
-# ---------------------------------------------------------------- guard 2
 def multidevice_skip(required: int = 4):
-    """(skip?, reason) for tests that need ``required`` devices.
-
-    The subprocess-based tests can force host devices via XLA_FLAGS, but
-    only on a jax recent enough to expose ``jax.sharding.AxisType`` (their
-    mesh construction uses it); on older jax or genuinely single-device
-    environments they must skip rather than fail.
-    """
+    """(skip?, reason) for tests that need ``required`` devices."""
     import jax
 
-    if not hasattr(jax.sharding, "AxisType"):
-        return True, "jax.sharding.AxisType unavailable (jax too old)"
     if jax.device_count() < required and jax.default_backend() != "cpu":
         return True, f"needs >= {required} devices (have {jax.device_count()})"
     return False, ""
+
+
+@functools.lru_cache(maxsize=None)
+def solo_run(case_name: str, quota: int, dispatch: str = "masked"):
+    """``(heap, value, stats)`` of a registered case run alone on a
+    ``HostEngine`` with ``capacity=quota``.  Cached: the result is shared
+    by every caller, which must treat it as read-only."""
+    from repro.apps import get_case
+    from repro.core import HostEngine
+
+    case = get_case(case_name)
+    return HostEngine(case.program, capacity=quota, dispatch=dispatch).run(
+        case.initial, heap_init=dict(case.heap_init) or None
+    )
+
+
+def assert_matches_solo(handle, solo_heap, solo_value, name):
+    """A finished job's value block and heap equal the solo run's."""
+    import numpy as np
+
+    r = handle.result
+    np.testing.assert_array_equal(
+        np.asarray(r.value), np.asarray(solo_value), err_msg=f"{name}:value"
+    )
+    assert set(r.heap) == set(solo_heap)
+    for k in solo_heap:
+        np.testing.assert_array_equal(
+            np.asarray(r.heap[k]), np.asarray(solo_heap[k]),
+            err_msg=f"{name}:{k}",
+        )

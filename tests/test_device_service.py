@@ -10,6 +10,7 @@ overhead: exactly one dispatch and one scalar readback.
 """
 import numpy as np
 import pytest
+from conftest import solo_run
 
 from repro.apps import fib, get_fleet
 from repro.core import HostEngine
@@ -24,8 +25,7 @@ from repro.service import (
 
 
 def _solo(case, quota):
-    eng = HostEngine(case.program, capacity=quota)
-    return eng.run(case.initial, heap_init=dict(case.heap_init) or None)
+    return solo_run(case.name, quota)
 
 
 def _handles(fleet):

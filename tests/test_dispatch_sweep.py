@@ -9,8 +9,7 @@ is a dynamic argument, so all three K choices re-enter one compiled
 loop; the megakernel cells run the chunk inside one persistent Pallas
 kernel, interpret mode on CPU).  Every run must be bit-identical per
 job: same TV value block, same heap, same solo-comparable epoch count.
-Uses hypothesis when installed, else the deterministic stub
-(``tests/_hypothesis_stub.py``).  A separate zero-retrace guard drives
+A separate zero-retrace guard drives
 identical consecutive megakernel waves through ``JobService`` and pins
 ``trace_count`` flat on the second wave.
 """
@@ -111,7 +110,7 @@ def test_all_dispatch_modes_and_chunks_bit_identical(members):
 
     for shards in (1, 2):
         handles = _handles(fleet)
-        ShardedFleet(handles, shards=shards, chunk=4).run()
+        ShardedFleet(handles, shards=shards, chunk=4, mesh=None).run()
         _assert_same(ref, _snapshot(handles), f"sharded:P={shards}")
 
     # the self-tuning axis: dispatch="auto" + chunk="auto" through the

@@ -19,6 +19,7 @@ def _run(script: str, devices: int = 4) -> str:
     env = {
         "PYTHONPATH": SRC,
         "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}",
+        "JAX_PLATFORMS": "cpu",
         "PATH": "/usr/bin:/bin",
         "HOME": "/tmp",
     }

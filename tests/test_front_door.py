@@ -10,6 +10,7 @@ strictness, the async submit/stream surface, lifecycle-clock injection,
 template-cache LRU accounting, and the virtual-clock loadgen gate.
 """
 import asyncio
+import functools
 
 import numpy as np
 import pytest
@@ -35,6 +36,7 @@ QUOTA = 256
 FIB_N = 9
 
 
+@functools.lru_cache(maxsize=None)
 def _solo():
     heap, value, stats = HostEngine(fib.PROGRAM, capacity=QUOTA).run(
         fib.initial(FIB_N)
@@ -51,7 +53,8 @@ def _mux(engine, handles, dispatch="masked"):
         return EpochMultiplexer(handles, dispatch=dispatch)
     if engine == "device":
         return DeviceMultiplexer(handles, dispatch=dispatch, chunk=2)
-    return ShardedFleet(handles, shards=2, dispatch=dispatch, chunk=2)
+    return ShardedFleet(handles, shards=2, dispatch=dispatch, chunk=2,
+                        mesh=None)
 
 
 # ------------------------------------------- preempt/resume bit-identity
@@ -433,7 +436,7 @@ def test_placement_controller_policy_mix():
 
 def test_sharded_fleet_auto_placement_runs():
     h = _handle(0)
-    fl = ShardedFleet([h], shards=2, chunk=2, placement="auto")
+    fl = ShardedFleet([h], shards=2, chunk=2, placement="auto", mesh=None)
     fl.admit(_handle(1))
     done = fl.run()
     assert len(done) == 2

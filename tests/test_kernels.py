@@ -22,10 +22,11 @@ def _tol(dtype):
 
 # ------------------------------------------------------------- fork_scan
 @pytest.mark.parametrize("n", [1, 8, 127, 1024, 4097])
-@pytest.mark.parametrize("block", [256, 1024])
-def test_fork_scan_shapes(n, block):
+@pytest.mark.parametrize("block_rows", [8, 16])
+def test_fork_scan_shapes(n, block_rows):
     x = RNG.randint(0, 7, n).astype(np.int32)
-    offs, tot = fork_scan(jnp.asarray(x), block=block, interpret=True)
+    offs, tot = fork_scan(jnp.asarray(x), block_rows=block_rows,
+                          interpret=True)
     ro, rt = ref.fork_scan_ref(jnp.asarray(x))
     np.testing.assert_array_equal(np.asarray(offs), np.asarray(ro))
     assert int(tot) == int(rt)
@@ -35,7 +36,7 @@ def test_fork_scan_shapes(n, block):
 @given(st.lists(st.integers(0, 9), min_size=1, max_size=300))
 def test_fork_scan_property(xs):
     x = jnp.asarray(np.asarray(xs, np.int32))
-    offs, tot = fork_scan(x, block=256, interpret=True)
+    offs, tot = fork_scan(x, block_rows=8, interpret=True)
     # offsets are the exclusive prefix sum: contiguous child allocation
     np.testing.assert_array_equal(
         np.asarray(offs), np.cumsum([0] + xs[:-1])
@@ -170,14 +171,14 @@ def test_ops_ref_dispatch_on_cpu():
 
 
 # ------------------------------------------------------------- type_rank
-@pytest.mark.parametrize("n,T,blk", [(50, 3, 256), (1024, 2, 256), (3000, 5, 1024)])
+@pytest.mark.parametrize("n,T,blk", [(50, 3, 8), (1024, 2, 8), (3000, 5, 16)])
 def test_type_rank_matches_oracle(n, T, blk):
     from repro.kernels.fork_compact import type_rank
     from repro.kernels.ref import type_rank_ref
 
     t = jnp.asarray(RNG.randint(0, T, n), jnp.int32)
     a = jnp.asarray(RNG.rand(n) < 0.7)
-    r, c = type_rank(t, a, T, block=blk, interpret=True)
+    r, c = type_rank(t, a, T, block_rows=blk, interpret=True)
     rr, cc = type_rank_ref(t, a, T)
     np.testing.assert_array_equal(np.asarray(r), np.asarray(rr))
     np.testing.assert_array_equal(np.asarray(c), np.asarray(cc))
@@ -195,7 +196,7 @@ def test_type_rank_compaction_property(lanes):
 
     t = jnp.asarray([x[0] for x in lanes], jnp.int32)
     a = jnp.asarray([x[1] for x in lanes])
-    r, c = type_rank(t, a, 4, block=256, interpret=True)
+    r, c = type_rank(t, a, 4, block_rows=8, interpret=True)
     cnp, rnp, anp, tnp = map(np.asarray, (c, r, a, t))
     starts = np.concatenate([[0], np.cumsum(cnp)[:-1]])
     if anp.any():
@@ -206,7 +207,7 @@ def test_type_rank_compaction_property(lanes):
 
 # -------------------------------------------------- segmented_fork_scan
 @pytest.mark.parametrize(
-    "n,n_segs,blk", [(64, 3, 32), (300, 5, 128), (1024, 2, 1024), (4097, 4, 1024)]
+    "n,n_segs,blk", [(64, 3, 8), (300, 5, 8), (1024, 2, 512), (4097, 4, 8)]
 )
 def test_segmented_fork_scan_matches_oracle(n, n_segs, blk):
     """A/B: the Pallas segmented scan (interpret mode) vs the jnp reference
@@ -217,7 +218,7 @@ def test_segmented_fork_scan_matches_oracle(n, n_segs, blk):
     counts = RNG.randint(0, 4, n).astype(np.int32)
     seg = RNG.randint(0, n_segs + 1, n).astype(np.int32)  # n_segs = unowned
     oi, ti = segmented_fork_scan(
-        jnp.asarray(counts), jnp.asarray(seg), n_segs, block=blk,
+        jnp.asarray(counts), jnp.asarray(seg), n_segs, block_rows=blk,
         interpret=True,
     )
     orf, trf = ref.segmented_fork_scan_ref(
@@ -254,3 +255,68 @@ def test_segmented_fork_offsets_ops_dispatch():
         offs, totals = ops.segmented_fork_offsets(counts, seg, 2, impl=impl)
         np.testing.assert_array_equal(np.asarray(offs), [0, 0, 1, 2])
         np.testing.assert_array_equal(np.asarray(totals), [1, 5])
+
+
+# ------------------------------------- fork_compact parity, ragged lengths
+@pytest.mark.parametrize("n", [3, 129, 1000, 3 * 1024 + 5])
+@pytest.mark.parametrize(
+    "kernel", ["fork_offsets", "segmented_fork_offsets", "type_rank",
+               "lane_pack"]
+)
+def test_fork_compact_parity_non_lane_multiple(kernel, n):
+    """The (rows, 128)-tiled kernels (interpret mode, through ``ops``)
+    equal their ``ref`` oracles bit for bit at lengths that are not a
+    multiple of the 128-lane row, with several 8-row grid steps for the
+    SMEM carry to cross."""
+    from repro.kernels import fork_compact as fc
+
+    counts = jnp.asarray(RNG.randint(0, 9, n), jnp.int32)
+    seg = jnp.asarray(RNG.randint(0, 4, n), jnp.int32)  # 3 = unowned
+    types = jnp.asarray(RNG.randint(0, 5, n), jnp.int32)
+    active = jnp.asarray(RNG.rand(n) < 0.6)
+    args = {
+        "fork_offsets": (counts,),
+        "segmented_fork_offsets": (counts, seg, 3),
+        "type_rank": (types, active, 5),
+        "lane_pack": (active,),
+    }[kernel]
+    small_blocks = {
+        "fork_offsets": fc.fork_scan,
+        "segmented_fork_offsets": fc.segmented_fork_scan,
+        "type_rank": fc.type_rank,
+        "lane_pack": fc.type_rank,
+    }[kernel]
+    want = getattr(ops, kernel)(*args, impl="ref")
+    got = getattr(ops, kernel)(*args, impl="interpret")
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    if kernel != "lane_pack":  # lane_pack rides type_rank's kernel
+        got8 = small_blocks(*args, block_rows=8, interpret=True)
+        for w, g in zip(want, got8):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize(
+    "kernel", ["fork_offsets", "segmented_fork_offsets", "type_rank",
+               "lane_pack"]
+)
+def test_fork_compact_parity_at_chip_capacity(kernel):
+    """At the 2^22 lanes ``chip_smoke.py`` runs natively, the kernels in
+    interpret mode (64 grid steps of the default 512-row block) equal
+    their ``ref`` oracles bit for bit."""
+    n = 1 << 22
+    rng = np.random.RandomState(22)
+    counts = jnp.asarray(rng.randint(0, 9, n), jnp.int32)
+    seg = jnp.asarray(rng.randint(0, 5, n), jnp.int32)  # 4 = unowned
+    types = jnp.asarray(rng.randint(0, 6, n), jnp.int32)
+    active = jnp.asarray(rng.rand(n) < 0.5)
+    args = {
+        "fork_offsets": (counts,),
+        "segmented_fork_offsets": (counts, seg, 4),
+        "type_rank": (types, active, 6),
+        "lane_pack": (active,),
+    }[kernel]
+    want = getattr(ops, kernel)(*args, impl="ref")
+    got = getattr(ops, kernel)(*args, impl="interpret")
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

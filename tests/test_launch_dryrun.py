@@ -114,6 +114,7 @@ print("LOWER_OK", compiled.cost_analysis()["flops"])
     env = {
         "PYTHONPATH": SRC,
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+        "JAX_PLATFORMS": "cpu",
         "PATH": "/usr/bin:/bin",
         "HOME": "/tmp",
     }

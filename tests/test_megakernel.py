@@ -29,7 +29,13 @@ from repro.core import DeviceEngine, HostEngine
 from repro.core.engine import _map_width_ladder, _span_width_ladder
 from repro.kernels import epoch_chunk
 from repro.kernels import ref as kref
-from repro.service import DeviceMultiplexer, Job, JobHandle, JobStatus
+from repro.service import (
+    DeviceMultiplexer,
+    Job,
+    JobHandle,
+    JobStatus,
+    WaveTemplate,
+)
 
 
 def _handles(fleet):
@@ -139,6 +145,9 @@ def test_fleet_megakernel_bit_identical(fleet_name, dispatch):
     (masked and gather), with the ChunkSummary-derived fleet stats
     matching exactly."""
     fleet = get_fleet(fleet_name)
+    # one wave template per driver, shared across K: the chunk bound is a
+    # dynamic operand, so every K re-enters the one compiled chunk loop
+    templates = {}
     for chunk in (1, 4, None):
         runs = {}
         for mega in (False, True):
@@ -146,7 +155,12 @@ def test_fleet_megakernel_bit_identical(fleet_name, dispatch):
             mux = DeviceMultiplexer(
                 handles, dispatch=dispatch, chunk=chunk, megakernel=mega,
                 megakernel_impl="interpret" if mega else "auto",
+                template=templates.get(mega),
             )
+            templates.setdefault(mega, WaveTemplate(
+                key=None, program=mux.program, slots=mux.slots,
+                loop=mux.loop,
+            ))
             mux.run()
             runs[mega] = (handles, mux.stats())
         (hb, sb), (hm, sm) = runs[False], runs[True]
@@ -172,11 +186,15 @@ def test_megakernel_chunk_readback_cadence():
     """A megakernel wave of E epochs at chunk K pays exactly ⌈E/K⌉
     dispatches + readbacks, same as the while_loop driver."""
     fleet = [(get_case("fib"), 512), (get_case("treewalk"), 512)]
+    template = None
     for chunk in (1, 4, None):
         handles = _handles(fleet)
         mux = DeviceMultiplexer(
             handles, chunk=chunk, megakernel=True,
-            megakernel_impl="interpret",
+            megakernel_impl="interpret", template=template,
+        )
+        template = template or WaveTemplate(
+            key=None, program=mux.program, slots=mux.slots, loop=mux.loop,
         )
         mux.run()
         s = mux.stats()

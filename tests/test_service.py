@@ -5,6 +5,7 @@ shared TVM must be *observationally invisible* to each tenant — per-job
 heaps, TV-value blocks, and work stats bit-identical to a solo
 ``HostEngine.run`` with ``capacity=quota`` — while the fleet pays strictly
 fewer fused dispatches + scalar readbacks than the sum of the solo runs.
+The registry-fleet equivalences are in ``tests/test_service_fleets.py``.
 """
 import jax.numpy as jnp
 import numpy as np
@@ -22,119 +23,6 @@ from repro.service import (
     JobStatus,
     fuse_programs,
 )
-
-
-def _solo(case, quota, dispatch="masked"):
-    eng = HostEngine(case.program, capacity=quota, dispatch=dispatch)
-    return eng.run(case.initial, heap_init=dict(case.heap_init) or None)
-
-
-def _assert_job_matches_solo(handle, solo_heap, solo_value, name):
-    r = handle.result
-    np.testing.assert_array_equal(
-        np.asarray(r.value), np.asarray(solo_value), err_msg=f"{name}:value"
-    )
-    assert set(r.heap) == set(solo_heap)
-    for k in solo_heap:
-        np.testing.assert_array_equal(
-            np.asarray(r.heap[k]), np.asarray(solo_heap[k]),
-            err_msg=f"{name}:{k}",
-        )
-
-
-# ------------------------------------------- the multi-tenant equivalence
-@pytest.mark.parametrize("dispatch", ["masked", "compacted", "gather"])
-def test_mixed_fleet_bit_identical_and_cheaper(dispatch):
-    """Acceptance: a mixed fleet of 3 registered apps through the service is
-    bit-identical per job to solo runs, with fleet V_inf (dispatches +
-    scalar transfers) strictly below the sum of the solo runs'."""
-    fleet = get_fleet("mixed3")
-    assert len(fleet) >= 3
-    solo = {}
-    solo_vinf = 0
-    for case, quota in fleet:
-        heap, value, stats = _solo(case, quota, dispatch)
-        solo[case.name] = (heap, value, stats)
-        solo_vinf += stats.dispatches + stats.scalar_transfers
-
-    svc = JobService(
-        capacity=sum(q for _, q in fleet), dispatch=dispatch
-    )
-    handles = [svc.submit_case(case, quota=q) for case, q in fleet]
-    done = svc.drain()
-    assert {h.job_id for h in done} == {h.job_id for h in handles}
-
-    for h in handles:
-        sh, sv, ss = solo[h.job.name]
-        assert h.status is JobStatus.DONE
-        _assert_job_matches_solo(h, sh, sv, h.job.name)
-        # per-job work accounting matches the solo run exactly
-        assert h.result.stats.epochs == ss.epochs
-        assert h.result.stats.tasks_executed == ss.tasks_executed
-        assert h.result.stats.total_forks == ss.total_forks
-        assert h.result.stats.peak_tv_slots == ss.peak_tv_slots
-
-    fs = svc.stats()
-    assert fs.dispatches + fs.scalar_transfers < solo_vinf
-    # fused global epochs = max over members, not the sum
-    assert fs.epochs == max(s[2].epochs for s in solo.values())
-    # cross-job frontier fusion is recorded as coalesced ranges
-    assert fs.ranges_coalesced > 0
-
-
-def test_fused_maps_match_solo():
-    """A map-bearing tenant (mergesort bulk payloads) stays bit-identical
-    when its map launches run against the fused, namespaced heap."""
-    fleet = [(c, q) for c, q in get_fleet("mixed4")
-             if c.name in ("mergesort", "fib")]
-    solo = {c.name: _solo(c, q) for c, q in fleet}
-    svc = JobService(capacity=sum(q for _, q in fleet))
-    handles = [svc.submit_case(c, quota=q) for c, q in fleet]
-    svc.drain()
-    for h in handles:
-        sh, sv, ss = solo[h.job.name]
-        _assert_job_matches_solo(h, sh, sv, h.job.name)
-        assert h.result.stats.epochs == ss.epochs
-    # the sorted output really is sorted (guard against trivially-equal
-    # garbage comparisons)
-    ms = [h for h in handles if h.job.name == "mergesort"][0]
-    n = ms.result.heap["inp"].shape[0]
-    out = np.asarray(ms.result.heap["src"])[:n]
-    np.testing.assert_array_equal(out, np.sort(np.asarray(ms.result.heap["inp"])))
-
-
-@pytest.mark.parametrize(
-    "policy,gang", [("round_robin", 1), ("round_robin", 2),
-                    ("deepest_first", 2)]
-)
-def test_pop_policies_preserve_results(policy, gang):
-    """Gang-limited pop policies change only the fusion schedule, never any
-    job's results."""
-    fleet = get_fleet("mixed3")
-    solo = {c.name: _solo(c, q) for c, q in fleet}
-    svc = JobService(
-        capacity=sum(q for _, q in fleet), pop_policy=policy, gang=gang
-    )
-    handles = [svc.submit_case(c, quota=q) for c, q in fleet]
-    svc.drain()
-    for h in handles:
-        sh, sv, _ = solo[h.job.name]
-        _assert_job_matches_solo(h, sh, sv, f"{policy}:{h.job.name}")
-
-
-def test_gang1_round_robin_is_fair_serialization():
-    """gang=1 degenerates to interleaved solo execution: fleet dispatches
-    equal the sum of per-job epochs (no fusion), and rotation gives every
-    job progress (completion order follows job size)."""
-    fleet = get_fleet("mixed3")
-    solo_epochs = {c.name: _solo(c, q)[2].epochs for c, q in fleet}
-    svc = JobService(
-        capacity=sum(q for _, q in fleet), pop_policy="round_robin", gang=1
-    )
-    for c, q in fleet:
-        svc.submit_case(c, quota=q)
-    svc.drain()
-    assert svc.stats().epochs == sum(solo_epochs.values())
 
 
 # --------------------------------------------------- streaming / reuse

@@ -9,7 +9,7 @@ sharding (and chunk-boundary rebalancing) moves jobs between shards but
 the summed per-shard ``tasks_executed``/``total_forks`` equal the solo
 totals exactly.  The shard_map mesh path (real devices, exercised in a
 subprocess with 8 forced host devices) and the single-device vmap
-fallback produce the same bits.
+simulation (``mesh=None``) produce the same bits.
 """
 import os
 import subprocess
@@ -17,6 +17,7 @@ import sys
 
 import numpy as np
 import pytest
+from conftest import solo_run
 
 from repro.apps import fib, get_fleet
 from repro.core import HostEngine
@@ -27,8 +28,7 @@ SRC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "src")
 
 
 def _solo(case, quota):
-    eng = HostEngine(case.program, capacity=quota)
-    return eng.run(case.initial, heap_init=dict(case.heap_init) or None)
+    return solo_run(case.name, quota)
 
 
 def _handles(fleet, tag=""):
@@ -58,12 +58,12 @@ def _assert_solo_identical(handle, solo):
 @pytest.mark.parametrize("shards", [1, 2, 4, 8])
 def test_fleet_bit_identical_to_solo_across_p(shards):
     """Every job's value block, heap, and solo-comparable stats match the
-    solo run exactly, whatever P (vmap fallback on one device)."""
+    solo run exactly, whatever P (the mesh=None vmap simulation)."""
     fleet = get_fleet("mixed3")
     solo = {c.name: _solo(c, q) for c, q in fleet}
 
     anchors = _handles(fleet)
-    fl = ShardedFleet(anchors, shards=shards, chunk=4)
+    fl = ShardedFleet(anchors, shards=shards, chunk=4, mesh=None)
     extra = _handles(fleet, "_b") + _handles(fleet, "_c")
     for h in extra:
         assert fl.admit(h)
@@ -89,7 +89,7 @@ def test_work_conservation_across_shards(shards):
 
     anchors = _handles(fleet)
     fl = ShardedFleet(anchors, shards=shards, chunk=4,
-                      placement="least_loaded")
+                      placement="least_loaded", mesh=None)
     for tag in ("_b", "_c")[: reps - 1]:
         for h in _handles(fleet, tag):
             assert fl.admit(h)
@@ -121,7 +121,7 @@ def test_rebalance_migrates_queued_jobs_off_hot_shards():
     def build(rebalance):
         handles = [JobHandle(i, j) for i, j in enumerate(case_jobs[:1])]
         fl = ShardedFleet(handles, shards=4, chunk=2, placement="sticky",
-                          rebalance=rebalance)
+                          rebalance=rebalance, mesh=None)
         for i, j in enumerate(case_jobs[1:], start=1):
             assert fl.admit(JobHandle(i, j))
         return fl
@@ -154,13 +154,14 @@ def test_placement_policies():
     refused (left for the service queue)."""
     fleet = get_fleet("mixed3")
     anchors = _handles(fleet)
-    fl = ShardedFleet(anchors, shards=3, chunk=4, placement="round_robin")
+    fl = ShardedFleet(anchors, shards=3, chunk=4, placement="round_robin",
+                      mesh=None)
     # anchors placed round-robin: one wave's worth spread over 3 shards
     assert sum(len(q) for q in fl._pending) == len(anchors)
     assert [len(q) for q in fl._pending] == [1, 1, 1]
 
     sticky = ShardedFleet(_handles(fleet), shards=3, chunk=4,
-                          placement="sticky")
+                          placement="sticky", mesh=None)
     a = _handles(fleet, "_a")
     b = _handles(fleet, "_b")
     for h in a + b:
@@ -194,7 +195,7 @@ def test_zero_retrace_under_migration_and_p_switch():
 
     svc = JobService(
         capacity=sum(q for _, q in fleet), engine="sharded", shards=2,
-        chunk=4, max_jobs=len(fleet), placement="sticky",
+        chunk=4, max_jobs=len(fleet), placement="sticky", mesh=None,
     )
     submit_all(svc, 3)  # sticky + heterogenous -> migrations happen
     svc.drain()
@@ -216,7 +217,7 @@ def test_zero_retrace_under_migration_and_p_switch():
     svc4 = JobService(
         capacity=sum(q for _, q in fleet), engine="sharded", shards=4,
         chunk=4, max_jobs=len(fleet),
-        template_cache=svc.template_cache,
+        template_cache=svc.template_cache, mesh=None,
     )
     submit_all(svc4, 2)
     svc4.drain()
@@ -239,7 +240,7 @@ def test_sharded_service_streams_and_matches_solo():
     solo = {c.name: _solo(c, q) for c, q in fleet}
     svc = JobService(
         capacity=sum(q for _, q in fleet), engine="sharded", shards=4,
-        chunk=4, max_jobs=len(fleet), placement="least_loaded",
+        chunk=4, max_jobs=len(fleet), placement="least_loaded", mesh=None,
     )
     hs = []
     for r in range(4):
@@ -261,18 +262,30 @@ def test_sharded_engine_validation():
 
 
 def test_fleet_mesh_fallback_and_shard_map_path():
-    """make_fleet_mesh degrades to None (vmap fallback) when the host has
-    too few devices; the real shard_map path runs in a subprocess with 8
-    forced host devices and must be bit-identical to solo."""
+    """make_fleet_mesh refuses more shards than devices (the vmap
+    simulation is asked for by name, ``mesh=None``, and the megakernel
+    refuses it); the real shard_map path runs in a subprocess with 8
+    forced host devices, with every restacked carry spread over the 8
+    devices, and must be bit-identical to solo."""
     from repro.launch.mesh import make_fleet_mesh
 
     assert make_fleet_mesh(1) is None  # P=1: never worth a mesh
     import jax
 
     if len(jax.devices()) < 64:
-        assert make_fleet_mesh(64) is None  # degraded, not an error
+        with pytest.raises(ValueError, match="devices"):
+            make_fleet_mesh(64)
+        with pytest.raises(ValueError, match="devices"):
+            ShardedFleet(_handles(get_fleet("mixed3")), shards=64)
+        with pytest.raises(ValueError, match="devices"):
+            JobService(engine="sharded", shards=64)
     with pytest.raises(ValueError):
         make_fleet_mesh(0)
+    mega = ShardedFleet(_handles(get_fleet("mixed3")), shards=2, chunk=4,
+                        mesh=None, megakernel=True,
+                        megakernel_impl="interpret")
+    with pytest.raises(ValueError, match="megakernel"):
+        mega.step()
 
     script = """
 import numpy as np
@@ -296,6 +309,14 @@ handles = [
 ]
 fl = ShardedFleet(handles, shards=8, chunk=4)
 assert fl.mesh is not None, "8 devices must yield a real fleet mesh"
+launches = []
+run_chunk_fleet = fl._loop.run_chunk_fleet
+def spy(carry, limits, **kw):
+    launches.append(min(
+        len(x.sharding.device_set) for x in jax.tree.leaves(carry)
+    ))
+    return run_chunk_fleet(carry, limits, **kw)
+fl._loop.run_chunk_fleet = spy
 for tag in ("_b", "_c"):
     for i, (c, q) in enumerate(fleet):
         assert fl.admit(JobHandle(100 + i, Job(
@@ -315,11 +336,13 @@ for h in done:
     assert h.result.stats.epochs == ss.epochs
 st = fl.stats()
 assert st.dispatches == fl.collective_steps
+assert launches and min(launches) == 8, launches
 print("SHARD_MAP_OK", fl.collective_steps)
 """
     env = {
         "PYTHONPATH": SRC,
         "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
+        "JAX_PLATFORMS": "cpu",
         "PATH": "/usr/bin:/bin",
         "HOME": "/tmp",
     }

@@ -12,10 +12,6 @@ three accounting invariants must hold:
 * the derived ratios ``utilization`` / ``map_utilization`` stay in
   [0, 1] (they feed the RATIO_BUCKETS histograms in ``obs/metrics.py``,
   whose top bucket is 1.0).
-
-Uses hypothesis when installed, else the deterministic stub
-(``tests/_hypothesis_stub.py``) — same idiom as
-``tests/test_dispatch_sweep.py``.
 """
 import numpy as np
 from hypothesis import given, settings, strategies as st

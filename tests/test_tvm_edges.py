@@ -150,7 +150,7 @@ def test_fork_scan_non_block_multiple_vs_cumsum(n):
     from repro.kernels.fork_compact import fork_scan
 
     x = RNG.randint(0, 5, n).astype(np.int32)
-    offs, total = fork_scan(jnp.asarray(x), block=256, interpret=True)
+    offs, total = fork_scan(jnp.asarray(x), block_rows=8, interpret=True)
     want = np.cumsum(x) - x
     np.testing.assert_array_equal(np.asarray(offs), want)
     assert int(total) == int(x.sum())
@@ -166,7 +166,7 @@ def test_type_rank_non_block_multiple_vs_cumsum(n):
     t = RNG.randint(0, T, n).astype(np.int32)
     a = RNG.rand(n) < 0.6
     rank, counts = type_rank(
-        jnp.asarray(t), jnp.asarray(a), T, block=256, interpret=True
+        jnp.asarray(t), jnp.asarray(a), T, block_rows=8, interpret=True
     )
     rank, counts = np.asarray(rank), np.asarray(counts)
     for tt in range(T):
