@@ -146,6 +146,7 @@ def _default_pack_fn(active):
     return kops.lane_pack(active)
 
 
+@jax.named_scope("trees.pack")
 def _frontier_mask(state, start, count, cen, P: int):
     """Per-lane active predicate of a popped NDRange frontier.
 
@@ -217,7 +218,7 @@ class MapLauncher:
             mstep = self._get_step(ml.map_id, P, D)
             with self.tracer.span(
                 "map", "host", map_id=ml.map_id, lanes=P, width=D,
-            ), self.tracer.annotation(f"trees:map{ml.map_id}"):
+            ):
                 heap = mstep(heap, ml.where, ml.argi, ml.argf)
             col.dispatch()
             # what to record is the collector's decision (NullStats ignores
@@ -556,7 +557,8 @@ class EpochLoop:
             def gfn(state, start, count, cen):
                 self._mark_trace()
                 _, active, _ = _frontier_mask(state, start, count, cen, P)
-                return pack_fn(active)
+                with jax.named_scope("trees.pack"):
+                    return pack_fn(active)
 
             self._gather_cache[P] = jax.jit(gfn)
         return self._gather_cache[P]
@@ -581,11 +583,12 @@ class EpochLoop:
 
             def step(state, heap, arena, start, perm):
                 self._mark_trace()
-                lanepos = perm[:G]
-                valid = lanepos >= 0
-                idx = jnp.where(valid, start + lanepos, state.capacity)
-                cidx = jnp.clip(idx, 0, state.capacity - 1)
-                cen_g = jnp.where(valid, state.epoch[cidx], 0)
+                with jax.named_scope("trees.pack"):
+                    lanepos = perm[:G]
+                    valid = lanepos >= 0
+                    idx = jnp.where(valid, start + lanepos, state.capacity)
+                    cidx = jnp.clip(idx, 0, state.capacity - 1)
+                    cen_g = jnp.where(valid, state.epoch[cidx], 0)
                 per_type, _ = tvm.trace_tasks(
                     program, state, heap, idx, valid, skip_idle_types=skip
                 )
@@ -619,11 +622,12 @@ class EpochLoop:
 
         def step(state, heap, arena, perm):
             self._mark_trace()
-            lanepos = perm[:W]
-            valid = lanepos >= 0
-            idx = jnp.where(valid, lanepos, state.capacity)
-            cidx = jnp.clip(idx, 0, state.capacity - 1)
-            cen_g = jnp.where(valid, state.epoch[cidx], 0)
+            with jax.named_scope("trees.pack"):
+                lanepos = perm[:W]
+                valid = lanepos >= 0
+                idx = jnp.where(valid, lanepos, state.capacity)
+                cidx = jnp.clip(idx, 0, state.capacity - 1)
+                cen_g = jnp.where(valid, state.epoch[cidx], 0)
             per_type, _ = tvm.trace_tasks(
                 program, state, heap, idx, valid, skip_idle_types=skip
             )
@@ -704,7 +708,7 @@ class EpochLoop:
             with tr.span(
                 "dispatch", "host", mode="compacted", launched=launched,
                 **dargs,
-            ), tr.annotation("trees:epoch_step"):
+            ):
                 state, heap, summary, map_launches = self.compacted_step(
                     P, buckets
                 )(
@@ -725,7 +729,7 @@ class EpochLoop:
             with tr.span(
                 "dispatch", "host", mode="gather", launched=G, holes=P - G,
                 **dargs,
-            ), tr.annotation("trees:epoch_step"):
+            ):
                 state, heap, summary, map_launches = self.gather_step(P, G)(
                     state, heap, arena, start_j, perm
                 )
@@ -734,7 +738,7 @@ class EpochLoop:
         else:
             with tr.span(
                 "dispatch", "host", mode="masked", launched=P, **dargs,
-            ), tr.annotation("trees:epoch_step"):
+            ):
                 state, heap, summary, map_launches = self.masked_step(P)(
                     state, heap, arena, start_j, count_j, cen_j
                 )
@@ -810,8 +814,9 @@ class EpochLoop:
                     # clamp so the window stays inside the TV; W covers the
                     # span, so the clamped window still contains every
                     # popped range (st <= lo and st + W >= span end)
-                    st = jnp.clip(lo, 0, capacity - W)
-                    cen_w = jax.lax.dynamic_slice(scen, (st,), (W,))
+                    with jax.named_scope("trees.pack"):
+                        st = jnp.clip(lo, 0, capacity - W)
+                        cen_w = jax.lax.dynamic_slice(scen, (st,), (W,))
                     s2, h2, summ, mls = step_fn(
                         state, heap, arena_, st,
                         jnp.asarray(W, jnp.int32), cen_w,
@@ -822,26 +827,29 @@ class EpochLoop:
                         state, heap, arena_, st, ct, scen
                     )
                 full = []
-                for ml in mls:
-                    zw = jnp.zeros((capacity,), bool)
-                    zi = jnp.zeros(
-                        (capacity,) + ml.argi.shape[1:], ml.argi.dtype
-                    )
-                    zf = jnp.zeros(
-                        (capacity,) + ml.argf.shape[1:], ml.argf.dtype
-                    )
-                    full.append(tvm.MapLaunch(
-                        map_id=ml.map_id,
-                        where=jax.lax.dynamic_update_slice(
-                            zw, ml.where, (st,)
-                        ),
-                        argi=jax.lax.dynamic_update_slice(
-                            zi, ml.argi, (st,) + (0,) * (ml.argi.ndim - 1)
-                        ),
-                        argf=jax.lax.dynamic_update_slice(
-                            zf, ml.argf, (st,) + (0,) * (ml.argf.ndim - 1)
-                        ),
-                    ))
+                with jax.named_scope("trees.maps"):
+                    for ml in mls:
+                        zw = jnp.zeros((capacity,), bool)
+                        zi = jnp.zeros(
+                            (capacity,) + ml.argi.shape[1:], ml.argi.dtype
+                        )
+                        zf = jnp.zeros(
+                            (capacity,) + ml.argf.shape[1:], ml.argf.dtype
+                        )
+                        full.append(tvm.MapLaunch(
+                            map_id=ml.map_id,
+                            where=jax.lax.dynamic_update_slice(
+                                zw, ml.where, (st,)
+                            ),
+                            argi=jax.lax.dynamic_update_slice(
+                                zi, ml.argi,
+                                (st,) + (0,) * (ml.argi.ndim - 1),
+                            ),
+                            argf=jax.lax.dynamic_update_slice(
+                                zf, ml.argf,
+                                (st,) + (0,) * (ml.argf.ndim - 1),
+                            ),
+                        ))
                 return s2, h2, summ, full
 
             return branch
@@ -856,135 +864,155 @@ class EpochLoop:
 
             def branch(state, heap, arena_, perm):
                 s2, h2, summ, mls = step_fn(state, heap, arena_, perm)
-                lanepos = perm[:W]
-                # invalid pack slots scatter to the drop index (capacity)
-                scat = jnp.where(lanepos >= 0, lanepos, capacity)
                 full = []
-                for ml in mls:
-                    zw = jnp.zeros((capacity,), bool)
-                    zi = jnp.zeros(
-                        (capacity,) + ml.argi.shape[1:], ml.argi.dtype
-                    )
-                    zf = jnp.zeros(
-                        (capacity,) + ml.argf.shape[1:], ml.argf.dtype
-                    )
-                    full.append(tvm.MapLaunch(
-                        map_id=ml.map_id,
-                        where=zw.at[scat].set(ml.where, mode="drop"),
-                        argi=zi.at[scat].set(ml.argi, mode="drop"),
-                        argf=zf.at[scat].set(ml.argf, mode="drop"),
-                    ))
+                with jax.named_scope("trees.maps"):
+                    lanepos = perm[:W]
+                    # invalid pack slots scatter to the drop index
+                    # (capacity)
+                    scat = jnp.where(lanepos >= 0, lanepos, capacity)
+                    for ml in mls:
+                        zw = jnp.zeros((capacity,), bool)
+                        zi = jnp.zeros(
+                            (capacity,) + ml.argi.shape[1:], ml.argi.dtype
+                        )
+                        zf = jnp.zeros(
+                            (capacity,) + ml.argf.shape[1:], ml.argf.dtype
+                        )
+                        full.append(tvm.MapLaunch(
+                            map_id=ml.map_id,
+                            where=zw.at[scat].set(ml.where, mode="drop"),
+                            argi=zi.at[scat].set(ml.argi, mode="drop"),
+                            argf=zf.at[scat].set(ml.argf, mode="drop"),
+                        ))
                 return s2, h2, summ, full
 
             return branch
 
         def body(carry: ResidentCarry):
             self._mark_trace()
-            cen, start, count, live, sp = batched_device_pop(
-                carry.jstack, carry.rstack, carry.sp
-            )
-            arena = carry.arena
-            if arena is None:
-                lo, ct = start[0], count[0]
-                span_w = jnp.where(live[0], count[0], 0)
-                if gather:
-                    # gather packs over the full TV, so the solo popped
-                    # range becomes a per-lane CEN vector like the fleet's
-                    lanes = jnp.arange(capacity, dtype=jnp.int32)
-                    in_pop = live[0] & (lanes >= lo) & (lanes < lo + ct)
-                    step_cen = jnp.where(in_pop, cen[0], 0)
+            # the phases of one epoch, named for the profiler (op-name
+            # metadata only: a scope adds no operation and no trace)
+            with jax.named_scope("trees.pop"):
+                cen, start, count, live, sp = batched_device_pop(
+                    carry.jstack, carry.rstack, carry.sp
+                )
+                arena = carry.arena
+                if arena is None:
+                    lo, ct = start[0], count[0]
+                    span_w = jnp.where(live[0], count[0], 0)
+                    if gather:
+                        # gather packs over the full TV, so the solo popped
+                        # range becomes a per-lane CEN vector like the
+                        # fleet's
+                        lanes = jnp.arange(capacity, dtype=jnp.int32)
+                        in_pop = live[0] & (lanes >= lo) & (lanes < lo + ct)
+                        step_cen = jnp.where(in_pop, cen[0], 0)
+                    else:
+                        step_cen = jnp.where(live[0], cen[0], 0)
                 else:
-                    step_cen = jnp.where(live[0], cen[0], 0)
-            else:
-                # fuse every live region's pop into a per-lane CEN vector
-                # over the full TV (work-together across regions); the task
-                # launch itself is then bucketed to the union span of the
-                # popped ranges — a wave with one hot region stops paying
-                # full-TV launches every epoch
-                J = arena.n_jobs
-                lanes = jnp.arange(capacity, dtype=jnp.int32)
-                jl = jnp.clip(arena.slot_job, 0, J - 1)
-                owned = arena.slot_job < J
-                in_pop = (
-                    owned & live[jl]
-                    & (lanes >= start[jl])
-                    & (lanes < start[jl] + count[jl])
-                )
-                step_cen = jnp.where(in_pop, cen[jl], 0)
-                big = jnp.asarray(capacity, jnp.int32)
-                span_lo = jnp.min(jnp.where(live, start, big))
-                span_hi = jnp.max(jnp.where(live, start + count, 0))
-                lo = jnp.clip(span_lo, 0, capacity)
-                ct = jnp.asarray(capacity, jnp.int32)
-                span_w = jnp.clip(span_hi - lo, 0, capacity)
+                    # fuse every live region's pop into a per-lane CEN
+                    # vector over the full TV (work-together across
+                    # regions); the task launch itself is then bucketed to
+                    # the union span of the popped ranges — a wave with one
+                    # hot region stops paying full-TV launches every epoch
+                    J = arena.n_jobs
+                    lanes = jnp.arange(capacity, dtype=jnp.int32)
+                    jl = jnp.clip(arena.slot_job, 0, J - 1)
+                    owned = arena.slot_job < J
+                    in_pop = (
+                        owned & live[jl]
+                        & (lanes >= start[jl])
+                        & (lanes < start[jl] + count[jl])
+                    )
+                    step_cen = jnp.where(in_pop, cen[jl], 0)
+                    big = jnp.asarray(capacity, jnp.int32)
+                    span_lo = jnp.min(jnp.where(live, start, big))
+                    span_hi = jnp.max(jnp.where(live, start + count, 0))
+                    lo = jnp.clip(span_lo, 0, capacity)
+                    ct = jnp.asarray(capacity, jnp.int32)
+                    span_w = jnp.clip(span_hi - lo, 0, capacity)
 
-            swarr = jnp.asarray(span_widths, jnp.int32)
-            if gather:
-                # the shared frontier predicate over the full TV: scheduled
-                # lanes are exactly those whose TV epoch TMS-matches the
-                # per-lane CEN of this epoch's popped ranges
-                act = (step_cen > 0) & (carry.state.epoch == step_cen)
-                perm, n_sched = pack_fn(act)
-                width_key = n_sched
-                branches = [make_gather_branch(W) for W in span_widths]
-                operands = (carry.state, carry.heap, arena, perm)
-            else:
-                width_key = span_w
-                branches = [
-                    make_branch(W, arena is not None) for W in span_widths
-                ]
-                operands = (
-                    carry.state, carry.heap, arena, step_cen, lo, ct
+            with jax.named_scope("trees.pack"):
+                swarr = jnp.asarray(span_widths, jnp.int32)
+                if gather:
+                    # the shared frontier predicate over the full TV:
+                    # scheduled lanes are exactly those whose TV epoch
+                    # TMS-matches the per-lane CEN of this epoch's popped
+                    # ranges
+                    act = (step_cen > 0) & (carry.state.epoch == step_cen)
+                    perm, n_sched = pack_fn(act)
+                    width_key = n_sched
+                    branches = [make_gather_branch(W) for W in span_widths]
+                    operands = (carry.state, carry.heap, arena, perm)
+                else:
+                    width_key = span_w
+                    branches = [
+                        make_branch(W, arena is not None)
+                        for W in span_widths
+                    ]
+                    operands = (
+                        carry.state, carry.heap, arena, step_cen, lo, ct
+                    )
+                sidx = jnp.clip(
+                    jnp.searchsorted(swarr, width_key, side="left"),
+                    0, len(span_widths) - 1,
                 )
-            sidx = jnp.clip(
-                jnp.searchsorted(swarr, width_key, side="left"),
-                0, len(span_widths) - 1,
-            )
-            if len(branches) == 1:
-                state, heap, summary, map_launches = branches[0](*operands)
-            else:
-                state, heap, summary, map_launches = jax.lax.switch(
-                    sidx, branches, *operands
+                hole_lanes = _hilo_add(
+                    carry.hole_lanes,
+                    jnp.asarray(capacity, jnp.int32) - swarr[sidx],
                 )
-            hole_lanes = _hilo_add(
-                carry.hole_lanes,
-                jnp.asarray(capacity, jnp.int32) - swarr[sidx],
-            )
-            if arena is None:
-                job_join = summary.join_scheduled[None]
-                job_forks = summary.total_forks[None]
-                job_next = state.next_free[None]
-                job_over = summary.overflow[None]
-                job_active = summary.n_active[None]
-                job_peak = jnp.maximum(carry.job_peak, job_next)
-            else:
-                job_join = summary.job_join
-                job_forks = summary.job_forks
-                job_next = summary.job_next
-                job_over = summary.job_overflow
-                job_active = summary.job_active
-                job_peak = jnp.maximum(
-                    carry.job_peak, summary.job_next - arena.base
+            # the rung's step: each branch scopes its own pack, commit and
+            # map padding; the rest of it is the tasks phase
+            with jax.named_scope("trees.tasks"):
+                if len(branches) == 1:
+                    state, heap, summary, map_launches = branches[0](
+                        *operands
+                    )
+                else:
+                    state, heap, summary, map_launches = jax.lax.switch(
+                        sidx, branches, *operands
+                    )
+            with jax.named_scope("trees.push"):
+                if arena is None:
+                    job_join = summary.join_scheduled[None]
+                    job_forks = summary.total_forks[None]
+                    job_next = state.next_free[None]
+                    job_over = summary.overflow[None]
+                    job_active = summary.n_active[None]
+                    job_peak = jnp.maximum(carry.job_peak, job_next)
+                else:
+                    job_join = summary.job_join
+                    job_forks = summary.job_forks
+                    job_next = summary.job_next
+                    job_over = summary.job_overflow
+                    job_active = summary.job_active
+                    job_peak = jnp.maximum(
+                        carry.job_peak, summary.job_next - arena.base
+                    )
+                    # the region cursors ride the carry — the device-side
+                    # equivalent of the host multiplexer's arena.next
+                    # update
+                    arena = dataclasses.replace(arena, next=summary.job_next)
+                failed = carry.failed | (live & job_over)
+                ok = live & ~failed
+                # LIFO push order exactly as the host scheduler (§4.3.3):
+                # join continuation below, this epoch's forked range on top
+                jstack, rstack, sp, of1 = batched_device_push(
+                    carry.jstack, carry.rstack, sp,
+                    cen, start, count, ok & job_join, stack_depth,
                 )
-                # the region cursors ride the carry — the device-side
-                # equivalent of the host multiplexer's arena.next update
-                arena = dataclasses.replace(arena, next=summary.job_next)
-            failed = carry.failed | (live & job_over)
-            ok = live & ~failed
-            # LIFO push order exactly as the host scheduler (§4.3.3): join
-            # continuation below, this epoch's forked range on top
-            jstack, rstack, sp, of1 = batched_device_push(
-                carry.jstack, carry.rstack, sp,
-                cen, start, count, ok & job_join, stack_depth,
-            )
-            jstack, rstack, sp, of2 = batched_device_push(
-                jstack, rstack, sp,
-                cen + 1, job_next - job_forks, job_forks,
-                ok & (job_forks > 0), stack_depth,
-            )
-            failed_stack = carry.failed_stack | of1 | of2
-            failed = failed | of1 | of2
-            sp = jnp.where(failed, 0, sp)
+                jstack, rstack, sp, of2 = batched_device_push(
+                    jstack, rstack, sp,
+                    cen + 1, job_next - job_forks, job_forks,
+                    ok & (job_forks > 0), stack_depth,
+                )
+                failed_stack = carry.failed_stack | of1 | of2
+                failed = failed | of1 | of2
+                sp = jnp.where(failed, 0, sp)
+                n_epochs = carry.n_epochs + 1
+                job_epochs = carry.job_epochs + live.astype(jnp.int32)
+                job_tasks = _hilo_add(carry.job_tasks, job_active)
+                job_forks_acc = _hilo_add(carry.job_forks, job_forks)
 
             # map payloads sized to a power-of-2 width bucket picked by a
             # traced max over the scheduled lanes' live domains: each bucket
@@ -998,81 +1026,84 @@ class EpochLoop:
             # per-element indices in the same stable lane order, so packing
             # the rows is bit-identical.  Residual padding waste (lane rung
             # x domain rung) stays accounted in ``map_lanes``.
-            map_ct = carry.map_launches
-            map_el = carry.map_elements
-            map_ln = carry.map_lanes
-            lane_widths = _span_width_ladder(capacity)
-            larr = jnp.asarray(lane_widths, jnp.int32)
-            for ml in map_launches:
-                mt = program.maps[ml.map_id]
-                if mt.max_domain <= 0:
-                    raise EngineError(
-                        f"map '{mt.name}' needs max_domain>0 for resident "
-                        "(device) execution"
+            with jax.named_scope("trees.maps"):
+                map_ct = carry.map_launches
+                map_el = carry.map_elements
+                map_ln = carry.map_lanes
+                lane_widths = _span_width_ladder(capacity)
+                larr = jnp.asarray(lane_widths, jnp.int32)
+                for ml in map_launches:
+                    mt = program.maps[ml.map_id]
+                    if mt.max_domain <= 0:
+                        raise EngineError(
+                            f"map '{mt.name}' needs max_domain>0 for resident "
+                            "(device) execution"
+                        )
+                    dom = jnp.clip(
+                        jnp.asarray(mt.domain(ml.argi), jnp.int32),
+                        0, mt.max_domain,
                     )
-                dom = jnp.clip(
-                    jnp.asarray(mt.domain(ml.argi), jnp.int32),
-                    0, mt.max_domain,
-                )
-                live_dom = jnp.where(ml.where, dom, 0)
-                dmax = live_dom.max().astype(jnp.int32)
-                # all-empty domains skip the launch (and its counters),
-                # exactly as the host MapLauncher does
-                fired = dmax > 0
-                widths = _map_width_ladder(mt.max_domain)
-                warr = jnp.asarray(widths, jnp.int32)
-                bidx = jnp.clip(
-                    jnp.searchsorted(warr, dmax, side="left"),
-                    0, len(widths) - 1,
-                )
-                lperm, lcount = pack_fn(ml.where)
-                lidx = jnp.clip(
-                    jnp.searchsorted(larr, lcount, side="left"),
-                    0, len(lane_widths) - 1,
-                )
+                    live_dom = jnp.where(ml.where, dom, 0)
+                    dmax = live_dom.max().astype(jnp.int32)
+                    # all-empty domains skip the launch (and its counters),
+                    # exactly as the host MapLauncher does
+                    fired = dmax > 0
+                    widths = _map_width_ladder(mt.max_domain)
+                    warr = jnp.asarray(widths, jnp.int32)
+                    bidx = jnp.clip(
+                        jnp.searchsorted(warr, dmax, side="left"),
+                        0, len(widths) - 1,
+                    )
+                    lperm, lcount = pack_fn(ml.where)
+                    lidx = jnp.clip(
+                        jnp.searchsorted(larr, lcount, side="left"),
+                        0, len(lane_widths) - 1,
+                    )
 
-                def make_lane_branch(L: int, _ml=ml):
-                    def lane_branch(h):
-                        rows = lperm[:L]
-                        valid = rows >= 0
-                        crows = jnp.clip(rows, 0, capacity - 1)
-                        w_p = valid & _ml.where[crows]
-                        argi_p = _ml.argi[crows]
-                        argf_p = _ml.argf[crows]
-                        inner = [
-                            lambda hh, _D=D: tvm.run_map_payload(
-                                program, hh, _ml.map_id, w_p, argi_p,
-                                argf_p, _D,
-                            )
-                            for D in widths
-                        ]
-                        if len(inner) == 1:
-                            return inner[0](h)
-                        return jax.lax.switch(bidx, inner, h)
+                    def make_lane_branch(L: int, _ml=ml):
+                        def lane_branch(h):
+                            rows = lperm[:L]
+                            valid = rows >= 0
+                            crows = jnp.clip(rows, 0, capacity - 1)
+                            w_p = valid & _ml.where[crows]
+                            argi_p = _ml.argi[crows]
+                            argf_p = _ml.argf[crows]
+                            inner = [
+                                lambda hh, _D=D: tvm.run_map_payload(
+                                    program, hh, _ml.map_id, w_p, argi_p,
+                                    argf_p, _D,
+                                )
+                                for D in widths
+                            ]
+                            if len(inner) == 1:
+                                return inner[0](h)
+                            return jax.lax.switch(bidx, inner, h)
 
-                    return lane_branch
+                        return lane_branch
 
-                branches = [lambda h: h] + [
-                    make_lane_branch(L) for L in lane_widths
-                ]
-                heap = jax.lax.switch(
-                    jnp.where(fired, lidx + 1, 0), branches, heap
-                )
-                fire_i = fired.astype(jnp.int32)
-                map_ct = map_ct + fire_i
-                map_el = _hilo_add(map_el, live_dom.sum().astype(jnp.int32))
-                map_ln = _hilo_add(
-                    map_ln, fire_i * larr[lidx] * warr[bidx]
-                )
+                    branches = [lambda h: h] + [
+                        make_lane_branch(L) for L in lane_widths
+                    ]
+                    heap = jax.lax.switch(
+                        jnp.where(fired, lidx + 1, 0), branches, heap
+                    )
+                    fire_i = fired.astype(jnp.int32)
+                    map_ct = map_ct + fire_i
+                    map_el = _hilo_add(
+                        map_el, live_dom.sum().astype(jnp.int32)
+                    )
+                    map_ln = _hilo_add(
+                        map_ln, fire_i * larr[lidx] * warr[bidx]
+                    )
 
             return ResidentCarry(
                 state=state, heap=heap, arena=arena,
                 jstack=jstack, rstack=rstack, sp=sp, failed=failed,
                 failed_stack=failed_stack,
-                n_epochs=carry.n_epochs + 1,
-                job_epochs=carry.job_epochs + live.astype(jnp.int32),
-                job_tasks=_hilo_add(carry.job_tasks, job_active),
-                job_forks=_hilo_add(carry.job_forks, job_forks),
+                n_epochs=n_epochs,
+                job_epochs=job_epochs,
+                job_tasks=job_tasks,
+                job_forks=job_forks_acc,
                 job_peak=job_peak,
                 map_launches=map_ct, map_elements=map_el, map_lanes=map_ln,
                 hole_lanes=hole_lanes,
@@ -1493,7 +1524,7 @@ class DeviceEngine:
             driver="device", mode=self.policy.name,
             megakernel=self.loop.megakernel,
         ) as sargs:
-            with tr.annotation("trees:resident_wave"):
+            with tr.span("resident_wave", "resident", tid=2):
                 out = self.loop.run_resident(carry, max_epochs, n_regions=1)
             # the one scalar transfer of the whole run
             with tr.span("readback", "resident", tid=2):

@@ -11,6 +11,11 @@ The epoch step implements the paper's three phases:
   phase 2 (execute)  — every task type runs as one masked dense vector op
   phase 3 (commit)   — prefix-sum fork allocation, TMS update  (this module)
 
+Each phase runs under a ``jax.named_scope`` (``trees.pack`` for the
+compaction stage, ``trees.tasks``, ``trees.commit``, ``trees.maps`` for the
+map payloads), so a profiler trace names the phase of every device
+operation it lowers to.
+
 The fork allocation replaces the paper's ``atomicInc(nextFreeCore)`` with an
 exclusive prefix sum over per-lane fork counts (TPU has no global atomics;
 the scan is deterministic and keeps children contiguous).  The scan itself is
@@ -210,6 +215,7 @@ def _make_lane_fn(program: Program, ttype, heap, values):
     return lane_fn
 
 
+@jax.named_scope("trees.tasks")
 def trace_tasks(
     program: Program,
     state: TVMState,
@@ -259,6 +265,7 @@ def trace_tasks(
     return per_type, cidx
 
 
+@jax.named_scope("trees.pack")
 def compact_types(
     program: Program,
     state: TVMState,
@@ -310,6 +317,7 @@ def compact_types(
     return perm, counts.astype(jnp.int32)
 
 
+@jax.named_scope("trees.tasks")
 def trace_tasks_compacted(
     program: Program,
     state: TVMState,
@@ -431,6 +439,7 @@ jax.tree_util.register_pytree_node(
 )
 
 
+@jax.named_scope("trees.commit")
 def commit_epoch(
     program: Program,
     state: TVMState,
@@ -637,6 +646,7 @@ def commit_epoch(
     return new_state, heap, summary, map_launches
 
 
+@jax.named_scope("trees.maps")
 def run_map_payload(
     program: Program,
     heap: Dict[str, jnp.ndarray],

@@ -212,6 +212,7 @@ class ShardedFleet:
                 ),
                 megakernel=megakernel,
                 megakernel_impl=megakernel_impl,
+                tracer=self.tracer,
                 controller=controller,
                 chunk_controller=self._kctl,
             )
@@ -428,7 +429,13 @@ class ShardedFleet:
         """One collective chunk: seat/rebalance queued jobs, advance every
         live shard by (at most) K epochs in ONE fused launch, read the
         stacked summaries back ONCE, then settle each shard's riders."""
-        self._seat_pending()
+        tr = self.tracer
+        if tr.enabled:
+            tr.thread(3, "fleet")
+            for p in range(self.shards):
+                tr.thread(10 + p, f"shard{p}")
+        with tr.span("admit", "fleet", tid=3):
+            self._seat_pending()
         riders = [
             [j for j, r in enumerate(sh._regions) if r.running]
             for sh in self._shards
@@ -442,24 +449,18 @@ class ShardedFleet:
             ],
             np.int32,
         )
-        fc = self._stacked()
-        J = len(self.template.slots)
         self.collective_steps += 1
-        tr = self.tracer
-        if tr.enabled:
-            tr.thread(3, "fleet")
-            for p in range(self.shards):
-                tr.thread(10 + p, f"shard{p}")
+        seq = self.collective_steps
         with tr.span(
             "collective_chunk", "fleet", tid=3,
-            seq=self.collective_steps, shards=self.shards,
+            seq=seq, shards=self.shards,
             jobs=sum(len(r) for r in riders),
             mode=self.policy.name,
             mesh=self.mesh is not None,
         ):
-            with tr.span("dispatch", "fleet", tid=3), tr.annotation(
-                "trees:fleet_chunk"
-            ):
+            fc = self._stacked()
+            J = len(self.template.slots)
+            with tr.span("resident_chunk", "fleet", seq=seq):
                 out = self._loop.run_chunk_fleet(
                     fc, limits, n_regions=J, n_shards=self.shards,
                     mesh=self.mesh,
@@ -467,36 +468,37 @@ class ShardedFleet:
             self._fcarry = out
             self._host = None
             self._fresh = [False] * self.shards
-            with tr.span("readback", "fleet", tid=3):
+            with tr.span("readback", "fleet", seq=seq):
                 summaries = self._loop.fleet_chunk_summaries(
                     out, self.shards
                 )
         self._dispatches += 1
         self._transfers += 1
         done: List[JobHandle] = []
-        for p, sh in enumerate(self._shards):
-            s = summaries[p]
-            self._last_sp[p] = s.sp
-            if not riders[p]:
-                continue
-            # a shard's carry is only pulled to the host when settling
-            # will actually touch it (a rider drained, failed, or hit the
-            # guard); quiet shards ride the next chunk without any host
-            # traffic on their state
-            if any(
-                bool(s.failed[j]) or int(s.sp[j]) == 0
-                or s.n_epochs >= max_epochs
-                for j in riders[p]
-            ):
-                self._view(p)
-            shard_done = sh._finish_chunk(s, riders[p], max_epochs)
-            done.extend(shard_done)
-            if tr.enabled:
-                with tr.span(
-                    "chunk", "fleet", tid=10 + p, shard=p,
-                    jobs=len(riders[p]), **sh.last_deltas,
+        with tr.span("settle", "fleet", tid=3, seq=seq):
+            for p, sh in enumerate(self._shards):
+                s = summaries[p]
+                self._last_sp[p] = s.sp
+                if not riders[p]:
+                    continue
+                # a shard's carry is only pulled to the host when settling
+                # will actually touch it (a rider drained, failed, or hit
+                # the guard); quiet shards ride the next chunk without any
+                # host traffic on their state
+                if any(
+                    bool(s.failed[j]) or int(s.sp[j]) == 0
+                    or s.n_epochs >= max_epochs
+                    for j in riders[p]
                 ):
-                    pass
+                    self._view(p)
+                shard_done = sh._finish_chunk(s, riders[p], max_epochs)
+                done.extend(shard_done)
+                if tr.enabled:
+                    with tr.span(
+                        "chunk", "fleet", tid=10 + p, shard=p,
+                        jobs=len(riders[p]), **sh.last_deltas,
+                    ):
+                        pass
         # controller feedback, ONCE per collective boundary: the fleet
         # queue is its internal shard queues plus whatever external queue
         # the service reports (the probe's optional third element is the

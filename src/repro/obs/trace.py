@@ -1,27 +1,42 @@
-"""Span tracing: Chrome-trace-event timelines for the epoch runtime.
+"""Span tracing: one span model for the epoch runtime, on two sinks.
 
 The paper's whole argument is an *accounting* one — V_inf critical-path
 overhead (dispatches + readbacks) should be paid once by the whole system —
 and the runtime already counts those terms in ``RunStats``/``ChunkSummary``.
-This module turns the counters into an observable timeline: a
-:class:`SpanTracer` collects Chrome trace events (the ``traceEvents`` JSON
-format that chrome://tracing and Perfetto load directly), and every driver
-emits spans against it:
+This module turns the counters into an observable timeline.  A span is one
+phase of host work, recorded twice:
+
+* as a ``jax.profiler.TraceAnnotation`` named ``trees:<name>``, so a
+  profiler session (``jax.profiler.trace``) holds the runtime's phases on
+  the same clock as the device operations.  The annotation carries the
+  span's numeric arguments (job id, chunk sequence number, quota) as
+  keyword arguments, so the event name itself stays bare;
+* as a Chrome trace event (the ``traceEvents`` JSON that chrome://tracing
+  and Perfetto load directly), collected by :class:`SpanTracer` and
+  written with :meth:`SpanTracer.write`.  Spans nest by thread: each
+  event records its enclosing span as ``args["parent"]``, and a span
+  given no ``tid`` lane takes its parent's.
+
+Every driver emits spans against it:
 
 * **host drivers** (``HostEngine``, ``EpochMultiplexer``) emit one
   ``epoch`` span per epoch with ``pack`` / ``dispatch`` / ``readback`` /
-  ``maps`` child phases — the V_inf terms as visible time, annotated with
+  ``map`` child phases — the V_inf terms as visible time, annotated with
   the CEN, dispatch mode, launch width, and lane utilization;
-* **resident drivers** (``DeviceEngine``, ``DeviceMultiplexer``, and the
-  megakernel path) cannot observe individual epochs without paying the
+* **resident drivers** (``DeviceEngine``, ``DeviceMultiplexer``,
+  ``ShardedFleet``) cannot observe individual epochs without paying the
   readbacks the design exists to avoid, so they emit one ``chunk`` span per
   chunk boundary, reconstructed from the :class:`~repro.core.engine.
-  ChunkSummary` deltas (epochs/tasks/holes run inside the chunk), with the
-  chunk's single ``readback`` as a child span — the trace makes the ⌈E/K⌉
-  readback cadence literally countable;
-* device launches are additionally wrapped in
-  ``jax.profiler.TraceAnnotation`` (:meth:`SpanTracer.annotation`) so an
-  XLA profiler session collected alongside lines up with the runtime spans.
+  ChunkSummary` deltas, with the chunk's launch (``resident_chunk``), its
+  single ``readback`` and its ``settle`` (``finalize`` per finished
+  region) as children — the trace makes the ⌈E/K⌉ readback cadence
+  literally countable; ``reseed`` marks each region seeded in flight;
+* the service (``JobService``) adds ``wave_build``, ``admit``, ``observe``
+  and ``preempt`` around the host work of each step.
+
+Inside the compiled epoch body the phases are ``jax.named_scope`` blocks
+(``trees.pop`` / ``pack`` / ``tasks`` / ``commit`` / ``push`` / ``maps``),
+which reach the profiler as each device operation's op-name path.
 
 Tracing is strictly opt-in: the module-level :data:`NULL_TRACER` is the
 default everywhere, its hooks are constant-time no-ops, and driver code
@@ -31,17 +46,20 @@ guards run with it in place).
 """
 from __future__ import annotations
 
-import contextlib
 import json
+import numbers
+import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional
+
+PROFILER_PREFIX = "trees:"
 
 
 class NullTracer:
     """Disabled tracer: every hook is a constant-time no-op.
 
-    ``span``/``annotation`` return a shared no-op context manager whose
-    ``__enter__`` yields a throwaway dict, so call sites can unconditionally
+    ``span`` returns a shared no-op context manager whose ``__enter__``
+    yields a throwaway dict, so call sites can unconditionally
     ``with tracer.span(...) as args: args.update(...)`` — though hot paths
     should still guard on ``tracer.enabled`` to skip building the args.
     """
@@ -57,19 +75,9 @@ class NullTracer:
 
     _NULL_SPAN = _NullSpan()
 
-    def span(self, name: str, cat: str = "runtime", tid: int = 0,
-             **args: Any):
+    def span(self, name: str, cat: str = "runtime",
+             tid: Optional[int] = None, **args: Any):
         return self._NULL_SPAN
-
-    def instant(self, name: str, cat: str = "runtime", tid: int = 0,
-                **args: Any) -> None:
-        return None
-
-    def counter(self, name: str, tid: int = 0, **values: float) -> None:
-        return None
-
-    def annotation(self, name: str):
-        return contextlib.nullcontext()
 
     def events_named(self, name: str) -> List[dict]:
         return []
@@ -79,7 +87,8 @@ NULL_TRACER = NullTracer()
 
 
 class SpanTracer(NullTracer):
-    """Collects Chrome trace events; write with :meth:`write`.
+    """Collects spans as Chrome trace events and profiler annotations;
+    write the events with :meth:`write`.
 
     Timestamps are microseconds since tracer construction
     (``perf_counter_ns`` based, so spans nest consistently within one
@@ -91,10 +100,14 @@ class SpanTracer(NullTracer):
     enabled = True
 
     def __init__(self, process_name: str = "trees-runtime", pid: int = 1):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
         self.pid = pid
         self.events: List[dict] = []
         self._t0 = time.perf_counter_ns()
         self._threads: Dict[int, str] = {}
+        self._open = threading.local()  # per-thread stack of open spans
         self.events.append({
             "ph": "M", "name": "process_name", "pid": pid, "tid": 0,
             "ts": 0, "args": {"name": process_name},
@@ -115,19 +128,29 @@ class SpanTracer(NullTracer):
             })
         return tid
 
+    def _stack(self) -> List[tuple]:
+        """This thread's open spans, innermost last: ``(name, tid)``."""
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
     # ------------------------------------------------------------- spans
     class _Span:
-        """Complete-event ("ph": "X") recorder.
+        """Complete-event ("ph": "X") recorder around a profiler
+        annotation.
 
         Yields its mutable ``args`` dict on ``__enter__`` so the caller can
         attach values only known at the end of the phase (lane utilization
-        after the readback, chunk deltas after the summary fetch).
+        after the readback, chunk deltas after the summary fetch); those
+        reach the Chrome event only — the annotation takes the numeric
+        arguments known at entry.
         """
 
-        __slots__ = ("_tr", "_name", "_cat", "_tid", "args", "_t0")
+        __slots__ = ("_tr", "_name", "_cat", "_tid", "args", "_t0", "_ann")
 
-        def __init__(self, tr: "SpanTracer", name: str, cat: str, tid: int,
-                     args: Dict[str, Any]):
+        def __init__(self, tr: "SpanTracer", name: str, cat: str,
+                     tid: Optional[int], args: Dict[str, Any]):
             self._tr = tr
             self._name = name
             self._cat = cat
@@ -135,45 +158,41 @@ class SpanTracer(NullTracer):
             self.args = args
 
         def __enter__(self) -> Dict[str, Any]:
-            self._t0 = self._tr.now_us()
+            tr = self._tr
+            stack = tr._stack()
+            if stack:
+                self.args["parent"] = stack[-1][0]
+            if self._tid is None:
+                self._tid = stack[-1][1] if stack else 0
+            stack.append((self._name, self._tid))
+            self._ann = tr._annotation(
+                PROFILER_PREFIX + self._name,
+                **{k: v for k, v in self.args.items()
+                   if isinstance(v, numbers.Number)},
+            )
+            self._ann.__enter__()
+            self._t0 = tr.now_us()
             return self.args
 
         def __exit__(self, *exc) -> None:
-            t1 = self._tr.now_us()
-            self._tr.events.append({
+            tr = self._tr
+            t1 = tr.now_us()
+            self._ann.__exit__(*exc)
+            tr._stack().pop()
+            tr.events.append({
                 "ph": "X", "name": self._name, "cat": self._cat,
-                "pid": self._tr.pid, "tid": self._tid,
+                "pid": tr.pid, "tid": self._tid,
                 "ts": self._t0, "dur": t1 - self._t0,
                 "args": self.args,
             })
             return None
 
-    def span(self, name: str, cat: str = "runtime", tid: int = 0,
-             **args: Any) -> "SpanTracer._Span":
-        """Context manager recording one complete event over its body."""
+    def span(self, name: str, cat: str = "runtime",
+             tid: Optional[int] = None, **args: Any) -> "SpanTracer._Span":
+        """Context manager recording one span over its body: a
+        ``trees:<name>`` profiler annotation and a Chrome complete
+        event."""
         return SpanTracer._Span(self, name, cat, tid, args)
-
-    def instant(self, name: str, cat: str = "runtime", tid: int = 0,
-                **args: Any) -> None:
-        self.events.append({
-            "ph": "i", "name": name, "cat": cat, "pid": self.pid,
-            "tid": tid, "ts": self.now_us(), "s": "t", "args": args,
-        })
-
-    def counter(self, name: str, tid: int = 0, **values: float) -> None:
-        """Counter-track sample (renders as a stacked area in Perfetto)."""
-        self.events.append({
-            "ph": "C", "name": name, "pid": self.pid, "tid": tid,
-            "ts": self.now_us(), "args": dict(values),
-        })
-
-    def annotation(self, name: str):
-        """``jax.profiler.TraceAnnotation`` wrapping a device launch, so an
-        XLA profile collected alongside shows the same phase names as the
-        runtime timeline."""
-        import jax.profiler
-
-        return jax.profiler.TraceAnnotation(name)
 
     # ----------------------------------------------------------- queries
     def events_named(self, name: str, cat: Optional[str] = None

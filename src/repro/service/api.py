@@ -41,6 +41,7 @@ from typing import (
 
 from ..core.program import InitialTask, Program
 from ..core.scheduler import RunStats
+from ..obs.trace import NULL_TRACER
 from .admission import AdmissionController, QuotaClass
 from .jobs import (
     AdmissionError,
@@ -278,7 +279,7 @@ class JobService:
         # adapter) and the per-tenant job lifecycle series below; ``tracer``
         # receives epoch/chunk span timelines from the wave drivers
         self.metrics = metrics
-        self.tracer = tracer
+        self.tracer = tracer or NULL_TRACER
         # self-tuning (DESIGN.md §14): the controllers live on the service
         # so what they learn carries across waves.  The dispatch controller
         # is shared by every wave's loop (host: per-epoch decisions;
@@ -644,156 +645,179 @@ class JobService:
 
     def _pump(self) -> List[JobHandle]:
         """Make one unit of progress: (re)build or refill the fleet, then
-        run one fused global epoch.  Returns newly completed handles."""
-        if self._mux is not None and not self._mux.live:
-            merge_stats(self._stats, self._mux.stats())
-            self._mux = None
-        if self._mux is None:
-            wave = self._take_wave()
-            if not wave:
+        run one fused global epoch.  Returns newly completed handles.
+
+        Each piece of host work is a span of its own (``wave_build``,
+        ``admit``, the driver's chunk spans, ``observe``, ``preempt``), so a
+        profiler trace names whatever the device waits on."""
+        tr = self.tracer
+        if self._mux is None or not self._mux.live:
+            with tr.span("wave_build", "service") as sargs:
+                if self._mux is not None:
+                    merge_stats(self._stats, self._mux.stats())
+                    self._mux = None
+                wave = self._take_wave()
+                if wave:
+                    hit = self._build_wave(wave)
+                    if tr.enabled:
+                        sargs.update(members=len(wave), template_hit=hit)
+            if self._mux is None:
                 return []
-            if self.engine in ("device", "sharded"):
-                # seat members in canonical order so a permutation of an
-                # earlier wave lands on the same slot layout as its cached
-                # template (the key is canonical too); each job's results
-                # attach to its own handle, so no un-permuting is needed
-                order = canonical_wave_order([h.job for h in wave])
-                wave = [wave[i] for i in order]
-                from ..core.engine import resolve_resident_dispatch
-
-                jobs = [h.job for h in wave]
-                cap = sum(h.job.quota for h in wave)
-
-                def _peek(cand: str):
-                    # sticky per wave shape: a cached template's baked
-                    # mode wins before the controller is ever consulted,
-                    # so an identical consecutive wave can never retrace
-                    # on a flipped decision; a *new* shape falls through
-                    # to the controller's accumulated cross-wave window
-                    return self.template_cache.peek(wave_template_key(
-                        jobs, cap, self.stack_depth, self.chunk,
-                        dispatch=cand, megakernel=self.megakernel,
-                    ))
-
-                dispatch_name = resolve_resident_dispatch(
-                    self.dispatch, self.controller, cap, peek=_peek
-                )
-                # the key is deliberately NOT a function of `shards`: a
-                # sharded fleet replicates ONE per-shard wave, so the same
-                # compiled template serves the solo wave and every P
-                key = wave_template_key(
-                    jobs, cap,
-                    self.stack_depth, self.chunk,
-                    dispatch=dispatch_name,
-                    megakernel=self.megakernel,
-                )
-                tpl = self.template_cache.lookup(key)
-                self._observe_template_cache(hit=tpl is not None)
-                if self.engine == "sharded":
-                    from ..distributed.fleet import ShardedFleet
-
-                    self._mux = ShardedFleet(
-                        wave,
-                        shards=self.shards,
-                        dispatch=dispatch_name,
-                        stack_depth=self.stack_depth,
-                        chunk=self.chunk,
-                        placement=self.placement,
-                        placement_controller=self.placement_controller,
-                        rebalance=self.rebalance,
-                        collect_stats=self.collect_stats,
-                        stats_factory=self._sharded_stats_factory(),
-                        template=tpl,
-                        megakernel=self.megakernel,
-                        megakernel_impl=self.megakernel_impl,
-                        tracer=self.tracer,
-                        controller=self.controller,
-                        chunk_controller=self.chunk_controller,
-                        queue_probe=self._queue_probe,
-                        mesh=self.mesh,
-                    )
-                    tpl_built = self._mux.template
-                    # the whole queue streams into the fleet's placement
-                    # queues up front: the anchor wave sized ONE shard's
-                    # layout, the other P-1 shards start vacant and fill
-                    # from here (and from later submits via streaming
-                    # admission)
-                    still = [
-                        h for h in self._queue if not self._mux.admit(h)
-                    ]
-                    self._queue = still
-                else:
-                    self._mux = DeviceMultiplexer(
-                        wave,
-                        dispatch=dispatch_name,
-                        stack_depth=self.stack_depth,
-                        chunk=self.chunk,
-                        collect_stats=self.collect_stats,
-                        stats_factory=self._stats_factory(),
-                        template=tpl,
-                        megakernel=self.megakernel,
-                        megakernel_impl=self.megakernel_impl,
-                        tracer=self.tracer,
-                        controller=self.controller,
-                        chunk_controller=self.chunk_controller,
-                        queue_probe=self._queue_probe,
-                    )
-                    tpl_built = WaveTemplate(
-                        key=key,
-                        program=self._mux.program,
-                        slots=self._mux.slots,
-                        loop=self._mux.loop,
-                    )
-                if tpl is None:
-                    self.template_cache.store(
-                        WaveTemplate(
-                            key=key,
-                            program=tpl_built.program,
-                            slots=tpl_built.slots,
-                            loop=tpl_built.loop,
-                        )
-                    )
-            else:
-                self._mux = EpochMultiplexer(
-                    wave,
-                    dispatch=self.dispatch,
-                    coalesce=self.coalesce,
-                    pop_policy=self.pop_policy,
-                    gang=self.gang,
-                    collect_stats=self.collect_stats,
-                    stats_factory=self._stats_factory(),
-                    rank_fn=self._rank_fn,
-                    tracer=self.tracer,
-                    controller=self.controller,
-                )
             self._admit_ready = False
         elif self._admit_ready and self._queue:
             # streaming admission: seed queued jobs into regions freed by
             # the completions (or preemptions) of the previous step — a
             # region can only free at those events, so skip the scan on
             # every other epoch
-            self._admit_queued()
+            with tr.span("admit", "service", queued=len(self._queue)):
+                self._admit_queued()
             self._admit_ready = False
         done = self._mux.step()
         if done:
             self._admit_ready = True
-            self._observe_completions(done)
+            with tr.span("observe", "service", jobs=len(done)):
+                self._observe_completions(done)
         # preemption (DESIGN.md §16): the step just crossed a chunk
         # boundary, the only place a region can yield.  Seat what free
         # regions absorb first — a free region always beats evicting work
         # — then ask admission who must yield for whoever is still stuck.
         if self.preemption and self._queue and self._mux.live:
-            self._admit_queued()
-            victims = self.admission.plan_preemptions(
-                self._mux.running_handles(), self._queue
-            ) if self._queue else []
-            for v in victims:
-                if self._mux.preempt(v):
-                    self.admission.note_preempted(v)
-                    self._observe_preemption(v)
-                    self._queue.append(v)
-                    self._admit_ready = True
+            with tr.span("admit", "service", queued=len(self._queue)):
+                self._admit_queued()
+            if self._queue:
+                with tr.span("preempt", "service", queued=len(self._queue)):
+                    victims = self.admission.plan_preemptions(
+                        self._mux.running_handles(), self._queue
+                    )
+                    for v in victims:
+                        if self._mux.preempt(v):
+                            self.admission.note_preempted(v)
+                            self._observe_preemption(v)
+                            self._queue.append(v)
+                            self._admit_ready = True
         return done
+
+    def _build_wave(self, wave: List[JobHandle]) -> Optional[bool]:
+        """Build the wave's driver into ``self._mux``; returns whether a
+        cached wave template served it (None for the host engine, which
+        keeps no templates)."""
+        hit = None
+        if self.engine in ("device", "sharded"):
+            # seat members in canonical order so a permutation of an
+            # earlier wave lands on the same slot layout as its cached
+            # template (the key is canonical too); each job's results
+            # attach to its own handle, so no un-permuting is needed
+            order = canonical_wave_order([h.job for h in wave])
+            wave = [wave[i] for i in order]
+            from ..core.engine import resolve_resident_dispatch
+
+            jobs = [h.job for h in wave]
+            cap = sum(h.job.quota for h in wave)
+
+            def _peek(cand: str):
+                # sticky per wave shape: a cached template's baked
+                # mode wins before the controller is ever consulted,
+                # so an identical consecutive wave can never retrace
+                # on a flipped decision; a *new* shape falls through
+                # to the controller's accumulated cross-wave window
+                return self.template_cache.peek(wave_template_key(
+                    jobs, cap, self.stack_depth, self.chunk,
+                    dispatch=cand, megakernel=self.megakernel,
+                ))
+
+            dispatch_name = resolve_resident_dispatch(
+                self.dispatch, self.controller, cap, peek=_peek
+            )
+            # the key is deliberately NOT a function of `shards`: a
+            # sharded fleet replicates ONE per-shard wave, so the same
+            # compiled template serves the solo wave and every P
+            key = wave_template_key(
+                jobs, cap,
+                self.stack_depth, self.chunk,
+                dispatch=dispatch_name,
+                megakernel=self.megakernel,
+            )
+            tpl = self.template_cache.lookup(key)
+            hit = tpl is not None
+            self._observe_template_cache(hit=hit)
+            if self.engine == "sharded":
+                from ..distributed.fleet import ShardedFleet
+
+                self._mux = ShardedFleet(
+                    wave,
+                    shards=self.shards,
+                    dispatch=dispatch_name,
+                    stack_depth=self.stack_depth,
+                    chunk=self.chunk,
+                    placement=self.placement,
+                    placement_controller=self.placement_controller,
+                    rebalance=self.rebalance,
+                    collect_stats=self.collect_stats,
+                    stats_factory=self._sharded_stats_factory(),
+                    template=tpl,
+                    megakernel=self.megakernel,
+                    megakernel_impl=self.megakernel_impl,
+                    tracer=self.tracer,
+                    controller=self.controller,
+                    chunk_controller=self.chunk_controller,
+                    queue_probe=self._queue_probe,
+                    mesh=self.mesh,
+                )
+                tpl_built = self._mux.template
+                # the whole queue streams into the fleet's placement
+                # queues up front: the anchor wave sized ONE shard's
+                # layout, the other P-1 shards start vacant and fill
+                # from here (and from later submits via streaming
+                # admission)
+                still = [
+                    h for h in self._queue if not self._mux.admit(h)
+                ]
+                self._queue = still
+            else:
+                self._mux = DeviceMultiplexer(
+                    wave,
+                    dispatch=dispatch_name,
+                    stack_depth=self.stack_depth,
+                    chunk=self.chunk,
+                    collect_stats=self.collect_stats,
+                    stats_factory=self._stats_factory(),
+                    template=tpl,
+                    megakernel=self.megakernel,
+                    megakernel_impl=self.megakernel_impl,
+                    tracer=self.tracer,
+                    controller=self.controller,
+                    chunk_controller=self.chunk_controller,
+                    queue_probe=self._queue_probe,
+                )
+                tpl_built = WaveTemplate(
+                    key=key,
+                    program=self._mux.program,
+                    slots=self._mux.slots,
+                    loop=self._mux.loop,
+                )
+            if tpl is None:
+                self.template_cache.store(
+                    WaveTemplate(
+                        key=key,
+                        program=tpl_built.program,
+                        slots=tpl_built.slots,
+                        loop=tpl_built.loop,
+                    )
+                )
+        else:
+            self._mux = EpochMultiplexer(
+                wave,
+                dispatch=self.dispatch,
+                coalesce=self.coalesce,
+                pop_policy=self.pop_policy,
+                gang=self.gang,
+                collect_stats=self.collect_stats,
+                stats_factory=self._stats_factory(),
+                rank_fn=self._rank_fn,
+                tracer=self.tracer,
+                controller=self.controller,
+            )
+        return hit
 
     def _admit_queued(self) -> int:
         """Try to seat queued jobs into free regions of the live wave, in

@@ -319,6 +319,8 @@ class _FleetBase:
     extraction.  The host and resident drivers differ only in *how* they
     drive epochs; everything either one reads or writes lives here."""
 
+    tracer = NULL_TRACER  # each driver sets its own in ``__init__``
+
     def __init__(
         self,
         handles: Sequence[JobHandle],
@@ -495,12 +497,21 @@ class _FleetBase:
                 s.program.structural_hash() != job.program.structural_hash()
             ):
                 continue
+            self._seat(r, handle)
+            return True
+        return False
+
+    def _seat(self, r: _Region, handle: JobHandle) -> None:
+        """Seat a job in a free region: restore its checkpoint, or seed it
+        fresh — one ``reseed`` span either way."""
+        with self.tracer.span(
+            "reseed", job_id=handle.job_id, quota=handle.job.quota,
+            region=r.slot.index, restore=handle.checkpoint is not None,
+        ):
             if handle.checkpoint is not None:
                 self._restore_region(r, handle)
             else:
                 self._seed_region(r, handle)
-            return True
-        return False
 
     def _admits_midflight(self) -> bool:
         return True
@@ -657,16 +668,18 @@ class _FleetBase:
         r = self._regions[j]
         s = r.slot
         sub = s.program
-        value = self._state.value[
-            s.base : s.base + r.active_quota, : sub.value_width
-        ]
-        heap = {
-            hv.name: self._heap[s.prefix + hv.name] for hv in sub.heap
-        }
-        r.handle.result = JobResult(heap=heap, value=value, stats=r.stats)
-        r.handle.status = JobStatus.DONE
-        r.handle.mark_finished()
-        return self._release(j)
+        with self.tracer.span("finalize", job_id=r.handle.job_id, region=j):
+            value = self._state.value[
+                s.base : s.base + r.active_quota, : sub.value_width
+            ]
+            heap = {
+                hv.name: self._heap[s.prefix + hv.name] for hv in sub.heap
+            }
+            r.handle.result = JobResult(heap=heap, value=value,
+                                        stats=r.stats)
+            r.handle.status = JobStatus.DONE
+            r.handle.mark_finished()
+            return self._release(j)
 
     def _fail(self, j: int, reason: Optional[str] = None) -> JobHandle:
         r = self._regions[j]
@@ -742,7 +755,7 @@ class EpochMultiplexer(_FleetBase):
         # resume preempted members: the host driver's runtime state is
         # fully built by now, so checkpointed wave members restore here
         for j, h in self._restore_pending:
-            self._restore_region(self._regions[j], h)
+            self._seat(self._regions[j], h)
         self._restore_pending = []
 
     @staticmethod
@@ -1163,41 +1176,42 @@ class DeviceMultiplexer(_FleetBase):
             # write each checkpoint image into its region
             self._ensure_carry()
             for j, h in self._restore_pending:
-                self._restore_region(self._regions[j], h)
+                self._seat(self._regions[j], h)
             self._restore_pending = []
         riders = [j for j, r in enumerate(self._regions) if r.running]
         if not riders:
             return []
         J = len(self._slots)
-        self._ensure_carry()
-        limit = self._chunk_limit(max_epochs)
         tr = self.tracer
         if tr.enabled:
             tr.thread(2, "resident")
         self._chunk_seq += 1
+        seq = self._chunk_seq
         # one "chunk" span per resident loop invocation, with the chunk's
-        # single dispatch and readback as children — a wave of E epochs
+        # launch, readback and settle as children — a wave of E epochs
         # renders as exactly ⌈E/K⌉ readback spans, the V_inf cadence made
         # countable.  Per-epoch detail inside the chunk is unobservable by
         # design (no readbacks to hang spans on); the deltas the readback
-        # reveals are attached to the span's args instead.
+        # reveals are attached to the span's args instead, and the
+        # profiler names the epoch body's phases on the device.
         with tr.span(
             "chunk", "resident", tid=2,
-            seq=self._chunk_seq, jobs=len(riders),
+            seq=seq, jobs=len(riders),
             k=(self._kctl.current() if self._kctl is not None
                else self.chunk),
             mode=self.policy.name, megakernel=self._loop.megakernel,
         ) as sargs:
-            with tr.span("dispatch", "resident", tid=2), tr.annotation(
-                "trees:resident_chunk"
-            ):
+            self._ensure_carry()
+            limit = self._chunk_limit(max_epochs)
+            with tr.span("resident_chunk", "resident", seq=seq):
                 carry = self._loop.run_chunk(self._carry, limit, n_regions=J)
             self._attach_carry(carry)
-            # the chunk's one readback (XLA launches are async: the dispatch
+            # the chunk's one readback (XLA launches are async: the launch
             # span above is enqueue time, this wait is the real chunk)
-            with tr.span("readback", "resident", tid=2):
+            with tr.span("readback", "resident", seq=seq):
                 s = self._loop.chunk_summary(carry)
-            done = self._finish_chunk(s, riders, max_epochs)
+            with tr.span("settle", "resident", seq=seq):
+                done = self._finish_chunk(s, riders, max_epochs)
             if tr.enabled:
                 sargs.update(self.last_deltas)
         # chunk-controller feedback: widen K while boundaries surface no

@@ -54,8 +54,6 @@ def test_span_tracer_writes_valid_chrome_trace(tmp_path):
         with tr.span("dispatch", "host", tid=1, launched=8):
             pass
         args.update(util=0.5)
-    tr.instant("admit", "service", tid=1, job="t0")
-    tr.counter("queue_depth", tid=1, queued=2)
     path = tmp_path / "trace.json"
     tr.write(str(path))
 
@@ -86,10 +84,6 @@ def test_null_tracer_is_inert():
     assert not NULL_TRACER.enabled
     with NULL_TRACER.span("epoch", foo=1) as args:
         args.update(bar=2)  # throwaway dict, must not raise
-    NULL_TRACER.instant("x")
-    NULL_TRACER.counter("x", v=1)
-    with NULL_TRACER.annotation("x"):
-        pass
     assert NULL_TRACER.events_named("epoch") == []
 
 
