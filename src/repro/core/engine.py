@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -61,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import tvm
+from ..obs.log import get_logger, kv
 from ..obs.trace import NULL_TRACER
 from .program import InitialTask, Program
 from .scheduler import (  # noqa: F401  (re-exports kept for back-compat)
@@ -81,6 +83,8 @@ from .scheduler import (  # noqa: F401  (re-exports kept for back-compat)
     resolve_policy,
     size_type_buckets,
 )
+
+log = get_logger("engine")
 
 
 class EngineError(RuntimeError):
@@ -452,6 +456,14 @@ class EpochLoop:
 
     def _mark_trace(self) -> None:
         self.trace_count += 1
+
+    @functools.cached_property
+    def commit_scatter_passes(self) -> int:
+        """Scatter passes into TV columns per epoch of this loop's commit
+        (:func:`~repro.core.tvm.commit_scatter_passes`: a static count of
+        the program, the same at every rung; read from an abstract trace
+        that compiles nothing and leaves ``trace_count`` alone)."""
+        return tvm.commit_scatter_passes(self.program)
 
     # ---------------------------------------------------- traced step bodies
     def _masked_step_fn(self, P: int):
@@ -1330,6 +1342,17 @@ class EpochLoop:
         )
 
 
+def log_run_stats(loop: EpochLoop, stats: RunStats, **fields) -> None:
+    """Log a finished run's (or wave's) counters with its commit plan."""
+    if log.isEnabledFor(logging.INFO):
+        flat = {k: v for k, v in stats.as_dict(derived=False).items()
+                if not isinstance(v, dict)}
+        log.info("run %s", kv(
+            **fields, **flat,
+            commit_scatter_passes=loop.commit_scatter_passes,
+        ))
+
+
 class HostEngine:
     """Paper-faithful engine: host drives stacks, device runs bulk epochs."""
 
@@ -1451,7 +1474,10 @@ class HostEngine:
             col.forks(int(total_forks))
             col.tv_peak(int(nf))
 
-        return heap, state.value, col.result()
+        stats = col.result()
+        log_run_stats(self.loop, stats, driver="host",
+                      program=program.name)
+        return heap, state.value, stats
 
 
 class DeviceEngine:
@@ -1548,4 +1574,6 @@ class DeviceEngine:
             hole_lanes_skipped=s.hole_lanes,
         )
         stats.peak_tv_slots = int(s.job_peak[0])
+        log_run_stats(self.loop, stats, driver="device",
+                      program=program.name)
         return out.heap, out.state.value, stats

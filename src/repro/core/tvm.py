@@ -439,6 +439,23 @@ jax.tree_util.register_pytree_node(
 )
 
 
+def _any(masks: Sequence[jnp.ndarray], P: int) -> jnp.ndarray:
+    """Lane-wise OR of per-type masks (all false when there are none)."""
+    if not masks:
+        return jnp.zeros((P,), bool)
+    return functools.reduce(jnp.logical_or, masks)
+
+
+def _by_type(pairs: Sequence[Tuple[jnp.ndarray, jnp.ndarray]]) -> jnp.ndarray:
+    """Merge per-type lane values under disjoint type masks: each lane takes
+    the value of the type whose mask holds it (lanes no mask holds take the
+    last type's, and are dropped by the scatter that reads them)."""
+    out = pairs[-1][1]
+    for m, v in reversed(pairs[:-1]):
+        out = jnp.where(m.reshape(m.shape + (1,) * (v.ndim - 1)), v, out)
+    return out
+
+
 @jax.named_scope("trees.commit")
 def commit_epoch(
     program: Program,
@@ -451,6 +468,7 @@ def commit_epoch(
     fork_offsets_fn: Optional[Callable] = None,
     seg_offsets_fn: Optional[Callable] = None,
     arena: Optional[JobArena] = None,
+    on_passes: Optional[Callable[[int], None]] = None,
 ) -> Tuple[TVMState, Dict[str, jnp.ndarray], EpochSummary, List[MapLaunch]]:
     """Phase 3: prefix-sum fork allocation + TMS (epoch-number) update.
 
@@ -474,6 +492,14 @@ def commit_epoch(
     ``kernels.ops.segmented_fork_offsets``.  This whole
     function is ``lax.while_loop``-traceable in both modes — the resident
     drivers carry the arena (cursors included) through the loop.
+
+    The TV writes are one plan merged across task types: one scatter pass
+    per column for each fork-site index (the types' ``i``-th sites share
+    it), one for the joins, one each for the parent pointers, the emitted
+    values and the finished entries.  A pass costs its full rung width
+    however few of its rows fire, so the commit's cost follows the pass
+    count.  ``on_passes(n)`` is called at trace time with the number of
+    scatter passes into TV columns this commit traced.
     """
     C = state.capacity
     P = idx.shape[0]
@@ -509,62 +535,75 @@ def commit_epoch(
         total_forks = job_forks.sum().astype(jnp.int32)
         overflow = job_overflow.any()
 
-    new_task = state.task
-    new_argi = state.argi
-    new_argf = state.argf
-    new_epoch = state.epoch
-    new_value = state.value
-    new_cb = state.child_base
-    new_cc = state.child_count
-
-    join_any = jnp.asarray(False)
-    lane_join = jnp.zeros((P,), bool)
-    map_any = jnp.asarray(False)
-    map_launches: List[MapLaunch] = []
+    cols = dict(
+        task=state.task, argi=state.argi, argf=state.argf,
+        epoch=state.epoch, value=state.value,
+        child_base=state.child_base, child_count=state.child_count,
+    )
+    passes = 0
     drop = C  # out-of-range slot => dropped scatter
 
-    for mask_t, eff in per_type:
-        # -------- forks: scatter children at contiguous prefix-sum slots
-        within = jnp.zeros((P,), jnp.int32)
-        for f in eff["forks"]:
-            fire = mask_t & f["where"]
-            raw = lane_base + within
-            if lane_cap is not None:
-                fire = fire & (raw < lane_cap)
-            slots = jnp.where(fire, raw, drop)
-            new_task = new_task.at[slots].set(f["task"], mode="drop")
-            new_argi = new_argi.at[slots].set(f["argi"], mode="drop")
-            new_argf = new_argf.at[slots].set(f["argf"], mode="drop")
-            new_epoch = new_epoch.at[slots].set(cen + 1, mode="drop")
-            new_cb = new_cb.at[slots].set(0, mode="drop")
-            new_cc = new_cc.at[slots].set(0, mode="drop")
-            within = within + fire.astype(jnp.int32)
+    def scatter(name, slots, vals):
+        # one full-rung scatter pass into a TV column; a zero-width column
+        # (no float args) holds nothing to write
+        nonlocal passes
+        if cols[name].size:
+            cols[name] = cols[name].at[slots].set(vals, mode="drop")
+            passes += 1
 
-        # -------- join: replace own entry; epoch number stays CEN
-        jw = jnp.zeros((P,), bool)
-        if eff["join"] is not None:
-            j = eff["join"]
-            jw = mask_t & j["where"]
-            jslots = jnp.where(jw, cidx, drop)
-            new_task = new_task.at[jslots].set(j["task"], mode="drop")
-            new_argi = new_argi.at[jslots].set(j["argi"], mode="drop")
-            new_argf = new_argf.at[jslots].set(j["argf"], mode="drop")
-            join_any = jnp.logical_or(join_any, jw.any())
-            lane_join = lane_join | jw
+    # The write plan is merged across task types: the type masks are
+    # disjoint, so one pass per column serves every type's lanes, each lane
+    # carrying its own type's values.  Child slots are fresh (at or past
+    # the region cursor), parent slots are the live lanes' own, and a lane
+    # has one type, so no two rows of the plan hit one slot and the merge
+    # writes exactly what a pass per type would.
+    owned = _any([m for m, _ in per_type], P)
 
+    # -------- forks, by site index: children at contiguous prefix-sum slots
+    within = jnp.zeros((P,), jnp.int32)
+    n_sites = max((len(e["forks"]) for _, e in per_type), default=0)
+    for i in range(n_sites):
+        sites = [(m, e["forks"][i]) for m, e in per_type
+                 if len(e["forks"]) > i]
+        fire = _any([m & f["where"] for m, f in sites], P)
+        raw = lane_base + within
+        if lane_cap is not None:
+            fire = fire & (raw < lane_cap)
+        slots = jnp.where(fire, raw, drop)
+        for name in ("task", "argi", "argf"):
+            scatter(name, slots, _by_type([(m, f[name]) for m, f in sites]))
+        scatter("epoch", slots, cen + 1)
+        scatter("child_base", slots, 0)
+        scatter("child_count", slots, 0)
+        within = within + fire.astype(jnp.int32)
+
+    # -------- join: replace own entry; epoch number stays CEN
+    joins = [(m, e["join"]) for m, e in per_type if e["join"] is not None]
+    lane_join = _any([m & j["where"] for m, j in joins], P)
+    join_any = lane_join.any()
+    if joins:
+        jslots = jnp.where(lane_join, cidx, drop)
+        for name in ("task", "argi", "argf"):
+            scatter(name, jslots, _by_type([(m, j[name]) for m, j in joins]))
+
+    if per_type:
         # -------- record children pointers on the (possibly joined) parent
-        pslots = jnp.where(mask_t, cidx, drop)
-        new_cb = new_cb.at[pslots].set(lane_base, mode="drop")
-        new_cc = new_cc.at[pslots].set(lane_count, mode="drop")
+        pslots = jnp.where(owned, cidx, drop)
+        scatter("child_base", pslots, lane_base)
+        scatter("child_count", pslots, lane_count)
 
         # -------- emit: store value; entry becomes invalid unless joined
-        ew = mask_t & eff["emit_where"]
-        eslots = jnp.where(ew, cidx, drop)
-        new_value = new_value.at[eslots].set(eff["emit_value"], mode="drop")
-        done = mask_t & jnp.logical_not(jw)
-        dslots = jnp.where(done, cidx, drop)
-        new_epoch = new_epoch.at[dslots].set(0, mode="drop")
+        ew = _any([m & e["emit_where"] for m, e in per_type], P)
+        scatter("value", jnp.where(ew, cidx, drop),
+                _by_type([(m, e["emit_value"]) for m, e in per_type]))
+        done = owned & jnp.logical_not(lane_join)
+        scatter("epoch", jnp.where(done, cidx, drop), 0)
+    if on_passes is not None:
+        on_passes(passes)
 
+    map_any = jnp.asarray(False)
+    map_launches: List[MapLaunch] = []
+    for mask_t, eff in per_type:
         # -------- heap writes (reads saw the pre-epoch snapshot)
         meta = eff["meta"].value
         for w, name, op in zip(
@@ -594,7 +633,7 @@ def commit_epoch(
 
     # ---- trailing-invalid reclamation (paper §5.3, nextFreeCore decrease)
     iota = jnp.arange(C, dtype=jnp.int32)
-    valid = new_epoch > 0
+    valid = cols["epoch"] > 0
     if arena is None:
         next_free = state.next_free + total_forks
         last_valid = jnp.max(jnp.where(valid, iota, -1))
@@ -633,17 +672,41 @@ def commit_epoch(
             job_next=job_next,
         )
 
-    new_state = TVMState(
-        task=new_task,
-        argi=new_argi,
-        argf=new_argf,
-        epoch=new_epoch,
-        value=new_value,
-        child_base=new_cb,
-        child_count=new_cc,
-        next_free=next_free,
-    )
+    new_state = TVMState(next_free=next_free, **cols)
     return new_state, heap, summary, map_launches
+
+
+def commit_scatter_passes(program: Program) -> int:
+    """Scatter passes into TV columns that :func:`commit_epoch` traces per
+    epoch of ``program`` when every task type runs (the masked and gather
+    dispatches, solo or arena): read from an abstract trace of one lane,
+    which compiles and runs nothing."""
+    counted: List[int] = []
+    sds = jax.ShapeDtypeStruct
+    C = 2
+    state = TVMState(
+        task=sds((C,), jnp.int32),
+        argi=sds((C, program.n_arg_i), jnp.int32),
+        argf=sds((C, program.n_arg_f), jnp.float32),
+        epoch=sds((C,), jnp.int32),
+        value=sds((C, program.value_width), program.value_dtype),
+        child_base=sds((C,), jnp.int32),
+        child_count=sds((C,), jnp.int32),
+        next_free=sds((), jnp.int32),
+    )
+    heap = {hv.name: sds(hv.shape, hv.dtype) for hv in program.heap}
+
+    def probe(state, heap):
+        idx = jnp.zeros((1,), jnp.int32)
+        active = jnp.ones((1,), bool)
+        per_type, _ = trace_tasks(program, state, heap, idx, active)
+        return commit_epoch(
+            program, state, heap, idx, active, per_type,
+            jnp.asarray(1, jnp.int32), on_passes=counted.append,
+        )[0]
+
+    jax.eval_shape(probe, state, heap)
+    return counted[-1]
 
 
 @jax.named_scope("trees.maps")

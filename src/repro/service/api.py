@@ -39,6 +39,7 @@ from typing import (
     Any, AsyncIterator, Callable, Dict, Iterator, List, Mapping, Optional,
 )
 
+from ..core.engine import log_run_stats
 from ..core.program import InitialTask, Program
 from ..core.scheduler import RunStats
 from ..obs.trace import NULL_TRACER
@@ -654,13 +655,21 @@ class JobService:
         if self._mux is None or not self._mux.live:
             with tr.span("wave_build", "service") as sargs:
                 if self._mux is not None:
-                    merge_stats(self._stats, self._mux.stats())
+                    wave_stats = self._mux.stats()
+                    log_run_stats(self._mux.loop, wave_stats,
+                                  driver=self.engine)
+                    merge_stats(self._stats, wave_stats)
                     self._mux = None
                 wave = self._take_wave()
                 if wave:
                     hit = self._build_wave(wave)
                     if tr.enabled:
-                        sargs.update(members=len(wave), template_hit=hit)
+                        sargs.update(
+                            members=len(wave), template_hit=hit,
+                            commit_scatter_passes=(
+                                self._mux.loop.commit_scatter_passes
+                            ),
+                        )
             if self._mux is None:
                 return []
             self._admit_ready = False

@@ -461,6 +461,11 @@ class _FleetBase:
         )
 
     @property
+    def loop(self) -> EpochLoop:
+        """The driver core (its steps and, resident, the chunk template)."""
+        return self._loop
+
+    @property
     def live(self) -> bool:
         return any(r.running for r in self._regions)
 
@@ -1091,11 +1096,6 @@ class DeviceMultiplexer(_FleetBase):
         self._chunk_seq = 0
         self._ledger = _ChunkLedger(len(self._slots))
         self.last_deltas: Dict[str, int] = {}
-
-    @property
-    def loop(self) -> EpochLoop:
-        """The driver core (owner of the compiled chunk template)."""
-        return self._loop
 
     @property
     def slots(self):

@@ -13,6 +13,7 @@
 """
 from __future__ import annotations
 
+import logging
 import lzma
 import os
 import re
@@ -76,6 +77,42 @@ def test_scopes_add_no_trace(dispatch):
         assert all(h.status.value == "done" for h in svc.drain())
         assert svc.template_cache.trace_count == TRACES_PER_TEMPLATE
     assert svc.template_cache.hits == 1
+
+
+def test_wave_build_span_carries_the_commit_plan():
+    """Each wave's build span names the commit plan it runs (a new
+    template's read from an abstract trace, a cached one's from its traced
+    loop) and a retired wave's stats line logs it; neither adds a trace."""
+    lines = []
+
+    class Keep(logging.Handler):
+        def emit(self, record):
+            lines.append(record.getMessage())
+
+    logger = logging.getLogger("repro.engine")
+    keep, level = Keep(), logger.level
+    logger.addHandler(keep)
+    logger.setLevel(logging.INFO)
+    tr = SpanTracer()
+    try:
+        svc = JobService(capacity=512, max_jobs=2, engine="device", chunk=2,
+                         dispatch="gather", tracer=tr)
+        for _ in range(2):
+            svc.submit(fib.PROGRAM, fib.initial(8), quota=256)
+            svc.submit(fib.PROGRAM, fib.initial(9), quota=256)
+            assert all(h.status.value == "done" for h in svc.drain())
+    finally:
+        logger.removeHandler(keep)
+        logger.setLevel(level)
+    # fib+fib: 2 fork-site indices x 5 columns, the join's task and args,
+    # the parent pointers, the emits and the finished entries
+    passes = [e["args"].get("commit_scatter_passes")
+              for e in tr.events_named("wave_build")]
+    assert passes == [16, 16]
+    assert svc._mux.loop.commit_scatter_passes == 16
+    assert svc.template_cache.trace_count == TRACES_PER_TEMPLATE
+    (line,) = [ln for ln in lines if ln.startswith("run ")]
+    assert "driver=device" in line and "commit_scatter_passes=16" in line
 
 
 # --------------------------------------------- (b) spans in the profiler
