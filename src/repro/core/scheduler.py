@@ -445,6 +445,11 @@ class RunStats:
     scalar_transfers: int = 0       # device->host readbacks (V_inf)
     ranges_coalesced: int = 0       # extra same-CEN ranges merged into pops
     hole_lanes_skipped: int = 0     # lanes a full-span launch would have paid
+    # a service's finished jobs, with their own epochs and tasks summed
+    # (per-job critical path and work; a solo run leaves them 0)
+    jobs_finished: int = 0
+    job_epochs_finished: int = 0
+    job_tasks_finished: int = 0
     tasks_by_type: Dict[str, int] = dataclasses.field(default_factory=dict)
     lanes_by_type: Dict[str, int] = dataclasses.field(default_factory=dict)
 
@@ -518,6 +523,9 @@ class RunStats:
         self.scalar_transfers += s.scalar_transfers
         self.ranges_coalesced += s.ranges_coalesced
         self.hole_lanes_skipped += s.hole_lanes_skipped
+        self.jobs_finished += s.jobs_finished
+        self.job_epochs_finished += s.job_epochs_finished
+        self.job_tasks_finished += s.job_tasks_finished
         for k, v in s.tasks_by_type.items():
             self.tasks_by_type[k] = self.tasks_by_type.get(k, 0) + v
         for k, v in s.lanes_by_type.items():
@@ -561,6 +569,11 @@ class StatsCollector:
     def tv_peak(self, slots: int) -> None:
         pass
 
+    def job_finished(self, epochs: int, tasks: int) -> None:
+        """A service job finished, after ``epochs`` epochs of its own in
+        which it ran ``tasks`` tasks."""
+        pass
+
     def result(self) -> RunStats:
         return RunStats()
 
@@ -584,6 +597,12 @@ class NullStats(StatsCollector):
     def map_launch(self, elements: int = 0, lanes: int = 0,
                    n: int = 1) -> None:
         self._stats.map_launches += n
+
+    def job_finished(self, epochs: int, tasks: int) -> None:
+        s = self._stats
+        s.jobs_finished += 1
+        s.job_epochs_finished += epochs
+        s.job_tasks_finished += tasks
 
     def result(self) -> RunStats:
         return self._stats

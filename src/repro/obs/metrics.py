@@ -376,5 +376,8 @@ class MetricsCollector(StatsCollector):
         self.inner.tv_peak(slots)
         self._peak.max(slots)
 
+    def job_finished(self, epochs: int, tasks: int) -> None:
+        self.inner.job_finished(epochs, tasks)
+
     def result(self) -> RunStats:
         return self.inner.result()

@@ -673,15 +673,18 @@ class _FleetBase:
         r = self._regions[j]
         s = r.slot
         sub = s.program
-        with self.tracer.span("finalize", job_id=r.handle.job_id, region=j):
+        st = r.stats
+        self._col.job_finished(st.epochs, st.tasks_executed)
+        with self.tracer.span("finalize", job_id=r.handle.job_id, region=j,
+                              epochs=st.epochs, tasks=st.tasks_executed,
+                              peak_tv_slots=st.peak_tv_slots):
             value = self._state.value[
                 s.base : s.base + r.active_quota, : sub.value_width
             ]
             heap = {
                 hv.name: self._heap[s.prefix + hv.name] for hv in sub.heap
             }
-            r.handle.result = JobResult(heap=heap, value=value,
-                                        stats=r.stats)
+            r.handle.result = JobResult(heap=heap, value=value, stats=st)
             r.handle.status = JobStatus.DONE
             r.handle.mark_finished()
             return self._release(j)

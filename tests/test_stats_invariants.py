@@ -12,14 +12,19 @@ three accounting invariants must hold:
 * the derived ratios ``utilization`` / ``map_utilization`` stay in
   [0, 1] (they feed the RATIO_BUCKETS histograms in ``obs/metrics.py``,
   whose top bucket is 1.0).
+
+A service's per-job counters (``jobs_finished``, ``job_epochs_finished``,
+``job_tasks_finished``) add up its finished jobs' own stats; a solo run
+leaves them 0.
 """
+import pytest
 import numpy as np
 from hypothesis import given, settings, strategies as st
 
 from repro.apps import fib, get_case
 from repro.core import DeviceEngine, HostEngine
 from repro.service import DeviceMultiplexer, EpochMultiplexer, Job, \
-    JobHandle, WaveTemplate
+    JobHandle, JobService, WaveTemplate
 
 _POOL = ("fib", "treewalk")
 _QUOTAS = (512, 1024)
@@ -119,3 +124,29 @@ def test_solo_driver_invariants():
     np.testing.assert_allclose(
         ds.utilization, ds.tasks_executed / max(1, ds.lanes_launched)
     )
+
+
+@pytest.mark.parametrize("engine", ["host", "device"])
+def test_job_counters_add_up_over_a_wave(engine):
+    """fib(8) and fib(10) share a wave of two regions, fib(7) and fib(9)
+    are seated as regions free: the fleet's per-job counters are the sums
+    of the four jobs' own stats."""
+    kw = dict(chunk=2, dispatch="gather") if engine == "device" else {}
+    svc = JobService(capacity=512, max_jobs=2, engine=engine, **kw)
+    for n in (8, 10, 7, 9):
+        svc.submit(fib.PROGRAM, fib.initial(n), quota=256)
+    handles = svc.drain()
+    assert [h.status.value for h in handles] == ["done"] * 4
+    s = svc.stats()
+    jobs = [h.result.stats for h in handles]
+    assert s.jobs_finished == 4
+    assert s.job_epochs_finished == sum(j.epochs for j in jobs)
+    assert s.job_tasks_finished == sum(j.tasks_executed for j in jobs)
+    assert s.job_tasks_finished == s.tasks_executed
+    d = s.as_dict()
+    assert (d["jobs_finished"], d["job_epochs_finished"],
+            d["job_tasks_finished"]) == (4, s.job_epochs_finished,
+                                         s.job_tasks_finished)
+    _, _, solo = HostEngine(fib.PROGRAM, capacity=256).run(fib.initial(8))
+    assert (solo.jobs_finished, solo.job_epochs_finished,
+            solo.job_tasks_finished) == (0, 0, 0)

@@ -157,6 +157,13 @@ def test_runtime_spans_reach_the_profiler_with_bare_names(tmp_path):
     fin = tr.events_named("finalize")
     assert {e["args"]["parent"] for e in fin} == {"settle"}
     assert len({e["args"]["job_id"] for e in fin}) == 3
+    # each finished job's own epochs, tasks and peak slots ride along
+    jobs = {h.job_id: h.result.stats for h in svc._handles.values()}
+    assert {e["args"]["job_id"]: (e["args"]["epochs"], e["args"]["tasks"],
+                                  e["args"]["peak_tv_slots"])
+            for e in fin} == {i: (st.epochs, st.tasks_executed,
+                                  st.peak_tv_slots)
+                              for i, st in jobs.items()}
 
 
 def test_null_tracer_emits_no_runtime_spans(tmp_path):
